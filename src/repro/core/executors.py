@@ -29,6 +29,7 @@ straggler behavior deterministic in tests.
 
 from __future__ import annotations
 
+import contextvars
 import dataclasses
 import hashlib
 import itertools
@@ -59,6 +60,19 @@ from repro.core.shuffle.base import LostShuffleInput
 
 class InjectedFailure(RuntimeError):
     pass
+
+
+#: the running task's ``stats`` dict. Executors are threads, so each
+#: thread's context carries its own task; code deep inside a fused batch
+#: operator (the device grouped sum) counts into it via ``task_stats()``
+_TASK_STATS: contextvars.ContextVar[dict | None] = contextvars.ContextVar(
+    "flint_task_stats", default=None)
+
+
+def task_stats() -> dict | None:
+    """The stats dict of the task running on this thread (None outside an
+    executor). Its counters come back in the task's response."""
+    return _TASK_STATS.get()
 
 
 class InvocationTimeout(RuntimeError):
@@ -968,6 +982,15 @@ class _ShuffleWriter:
 def executor_main(payload: dict, env: LambdaSim) -> dict:
     """The Lambda function body: deserialize task, build input iterator,
     run the pipeline, sink outputs, chain if the lease runs out."""
+    stats: dict[str, Any] = {"records_in": 0}
+    token = _TASK_STATS.set(stats)
+    try:
+        return _run_task(payload, env, stats)
+    finally:
+        _TASK_STATS.reset(token)
+
+
+def _run_task(payload: dict, env: LambdaSim, stats: dict) -> dict:
     fail_after = payload.get("fail_after_records")
     timeout_after = payload.get("timeout_after_records")
     inject = payload.get("inject_failure")
@@ -980,7 +1003,6 @@ def executor_main(payload: dict, env: LambdaSim) -> dict:
 
     lease = _Lease(env.cfg)
     src_id = f"s{payload['stage']}t{payload['index']}"
-    stats: dict[str, Any] = {"records_in": 0}
     inp = payload["input"]
     # a task carrying a cache op never chains: the tee must see the FULL
     # partition in one link so its content-addressed pack is deterministic

@@ -212,6 +212,11 @@ class FlintScheduler:
         # and lineage resubmissions (docs/fault_tolerance.md)
         self.recovery_stats = {"throttled": 0, "lost_inputs": 0,
                                "stage_resubmits": 0, "replayed_tasks": 0}
+        # device backend (vector_backend="jax"): compiled-kernel grouped
+        # sums, x64 segment sums, and sums past 2**62 handed back to the
+        # host's exact path — summed over successful task responses
+        self.device_stats = {"kernel_calls": 0, "x64_sums": 0,
+                             "device_fallbacks": 0}
         self._dispatch_sleep = 0.0  # decorrelated-jitter state, 0 = idle
         self._backoff_rng = random.Random(plan.seed ^ 0x5DEECE66D)
         self._stage_retries: dict[int, int] = {}  # stage idx -> resubmits
@@ -361,8 +366,12 @@ class FlintScheduler:
     def _note_shuffle_stats(self, stage: StagePlan, resp: dict):
         """Fold one successful response's per-partition shuffle-output
         deltas (wire bytes, records) into the running measurement for the
-        stage's shuffle — the feedback signal every replan decision reads."""
-        out = (resp.get("stats") or {}).get("shuffle_out")
+        stage's shuffle — the feedback signal every replan decision reads.
+        The response's device counters fold into ``device_stats`` here too."""
+        stats = resp.get("stats") or {}
+        for k in self.device_stats:
+            self.device_stats[k] += stats.get(k, 0)
+        out = stats.get("shuffle_out")
         if not out or stage.write is None:
             return
         agg = self.shuffle_stats.setdefault(stage.write.shuffle_id, {})

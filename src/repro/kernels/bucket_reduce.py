@@ -8,8 +8,8 @@ shuffle is a matmul" (DESIGN.md §2). This is also exactly the GShard MoE
 dispatch primitive, which is why the same kernel services reduceByKey-style
 aggregation and expert dispatch.
 
-Grid (N/bn,): the (P, D) accumulator persists in VMEM scratch across the
-sequential grid and is written out once.
+Grid (N/bn,): the (D, P) accumulator persists in VMEM scratch across the
+sequential grid and is written out once, transposed to (P, D).
 """
 
 from __future__ import annotations
@@ -34,19 +34,29 @@ def _kernel(ids_ref, vals_ref, o_ref, acc_ref, *, n_buckets: int, bn: int,
     vals = vals_ref[...].astype(jnp.float32)  # (bn, d)
     buckets = jax.lax.broadcasted_iota(jnp.int32, (bn, n_buckets), 1)
     onehot = (ids[:, None] == buckets).astype(jnp.float32)  # (bn, P)
-    # MXU: (P, bn) @ (bn, d) accumulated in f32 VMEM scratch
+    # MXU: (d, bn) @ (bn, P) accumulated in f32 VMEM scratch, buckets on
+    # the lanes (a (P, d) accumulator pads d=1 to 128 lanes and runs out of
+    # VMEM at 8192 buckets). HIGHEST keeps full-f32 passes: the chip's
+    # default precision rounds f32 operands to bf16, which would break the
+    # exact integer sums grouped_reduce needs
     acc_ref[...] += jax.lax.dot_general(
-        onehot, vals, (((0,), (0,)), ((), ())))
+        vals, onehot, (((0,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)
 
     @pl.when(step == nblocks - 1)
     def _finalize():
         o_ref[...] = acc_ref[...].astype(o_ref.dtype)
 
 
-def bucket_reduce(values, bucket_ids, n_buckets: int, *, block: int = 512,
+def bucket_reduce(values, bucket_ids, n_buckets: int, *, block: int = 1024,
                   interpret: bool = False):
-    """values: (N, D); bucket_ids: (N,) int32 in [0, n_buckets).
-    Returns per-bucket sums (n_buckets, D)."""
+    """values: (N, D); bucket_ids: (N,) int32 in [0, n_buckets), -1 for
+    padding. Returns per-bucket sums (n_buckets, D).
+
+    ``block`` is 1024 because XLA tiles a 1-D int32 array in HBM by 1024:
+    a smaller ``ids`` block does not match that layout and Mosaic refuses
+    the kernel on the TPU."""
     n, d = values.shape
     bn = min(block, n)
     pad = (-n) % bn
@@ -62,8 +72,8 @@ def bucket_reduce(values, bucket_ids, n_buckets: int, *, block: int = 512,
             pl.BlockSpec((bn,), lambda i: (i,)),
             pl.BlockSpec((bn, d), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((n_buckets, d), lambda i: (0, 0)),
-        out_shape=jax.ShapeDtypeStruct((n_buckets, d), values.dtype),
-        scratch_shapes=[pltpu.VMEM((n_buckets, d), jnp.float32)],
+        out_specs=pl.BlockSpec((d, n_buckets), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct((d, n_buckets), values.dtype),
+        scratch_shapes=[pltpu.VMEM((d, n_buckets), jnp.float32)],
         interpret=interpret,
-    )(bucket_ids, values)
+    )(bucket_ids, values).T

@@ -32,9 +32,12 @@ original row closures:
     per-slot when NaN is present (Python's min/max keep the FIRST value
     on NaN, numpy propagates or ignores it).
 
-In fact the fused operator treats ANY exception from a vectorized chunk
+In fact the fused operator treats any exception from a vectorized chunk
 as a fallback signal and re-runs the chunk through the row closures, so
-a divergence can only ever cost speed, never correctness.
+a divergence can only ever cost speed, never correctness. The one
+exception is ``DeviceBackendError``: a failure of the jax backend
+(import, compile or run) surfaces to the caller instead of quietly
+becoming a host answer.
 """
 
 from __future__ import annotations
@@ -70,6 +73,12 @@ class VectorFallback(Exception):
     path would diverge from row semantics (int64 overflow risk, ints past
     2**53 in a float comparison, non-conforming input rows). The fused
     operator re-runs just that chunk through the bound row closures."""
+
+
+class DeviceBackendError(RuntimeError):
+    """The jax backend (vector_backend="jax") failed to import, compile or
+    run a grouped sum. The fused operator re-raises it rather than re-run
+    the chunk on the host, so a broken device path cannot hide."""
 
 
 # ---------------------------------------------------------- column helpers
@@ -508,19 +517,20 @@ def _py_fold(op, vals, gids, ng):
 
 def _jax_int_sum(col, gids, ng):
     """Route an int64 group sum through the kernels/ backend
-    (FLINT_VECTOR_BACKEND=jax). Integer addition is associative, so an
+    (vector_backend="jax"). Integer addition is associative, so an
     order-free segment sum is exact as long as it cannot overflow — the
-    same magnitude bound as the numpy path. Returns None to defer to the
-    numpy path when jax is unavailable or the bound fails."""
+    same magnitude bound as the numpy path. Returns None only past that
+    bound, where the numpy path's bigint fold takes over; every backend
+    failure raises DeviceBackendError. Counts land in the running task's
+    stats."""
+    from repro.core.executors import task_stats
     try:
         from repro.kernels.ops import grouped_reduce
-    except Exception:
-        return None
-    try:
-        out = grouped_reduce(col, gids, ng)
-    except Exception:
-        return None
-    return None if out is None else np.asarray(out, dtype=np.int64)
+        return grouped_reduce(col, gids, ng, stats=task_stats())
+    except Exception as e:
+        raise DeviceBackendError(
+            f"jax grouped sum over {len(col)} rows / {ng} groups failed: "
+            f"{type(e).__name__}: {e}") from e
 
 
 # ---------------------------------------------------------- fused operator
@@ -539,9 +549,10 @@ def make_fused(ingest, stages, emit, row_chain, batch_rows):
     """Build the batch-in/batch-out fused operator for RDD.mapBatches:
     chunk the partition iterator, run ingest -> stages -> emit per chunk
     under strict float error traps, and re-run any chunk that raises
-    through ``row_chain`` (the exact per-row closure pipeline for the
-    same plan segment). Emissions are materialized per chunk BEFORE
-    yielding so a mid-chunk fallback never double-emits."""
+    (other than DeviceBackendError) through ``row_chain`` (the exact
+    per-row closure pipeline for the same plan segment). Emissions are
+    materialized per chunk BEFORE yielding so a mid-chunk fallback never
+    double-emits."""
     def fused(it):
         for chunk in _chunks(it, batch_rows):
             try:
@@ -551,6 +562,8 @@ def make_fused(ingest, stages, emit, row_chain, batch_rows):
                     for stage in stages:
                         cols, n = stage(cols, n)
                     out = emit(cols, n)
+            except DeviceBackendError:
+                raise
             except Exception:
                 out = list(row_chain(iter(chunk)))
             yield from out

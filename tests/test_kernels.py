@@ -1,4 +1,7 @@
-"""Per-kernel interpret=True sweeps against the pure-jnp oracles."""
+"""Per-kernel interpret=True sweeps against the pure-jnp oracles, and the
+exactness envelopes and bounded shapes of grouped_reduce."""
+
+import os
 
 import jax
 import jax.numpy as jnp
@@ -72,3 +75,82 @@ def test_flash_attention_grad_flows():
     v = jax.random.normal(jax.random.PRNGKey(2), (1, 128, 2, 32))
     g = jax.grad(lambda q: ops.flash_attention(q, k, v).sum())(q)
     assert bool(jnp.isfinite(g).all()) and float(jnp.abs(g).sum()) > 0
+
+
+def _bigint_fold(vals, ids, n):
+    acc = [0] * n
+    for i, v in zip(ids.tolist(), vals.tolist()):
+        acc[i] += v
+    return acc
+
+
+@pytest.mark.parametrize("case,path", [
+    ("small", "kernel_calls"),      # sum(|v|) < 2**24: the one-hot kernel
+    ("near_2_24", "kernel_calls"),  # one partial climbs to 2**24 - 1
+    ("big", "x64_sums"),            # up to 2**62: the x64 segment sum
+    ("wide", "x64_sums"),           # more groups than the kernel takes
+    ("over", "device_fallbacks"),   # past 2**62: handed back to the host
+    ("int64_min", "device_fallbacks"),
+])
+def test_grouped_reduce_envelopes_are_exact(case, path):
+    """Each envelope of grouped_reduce gives the bigint fold's int64 sums
+    (or None past 2**62), and counts the path it took."""
+    rng = np.random.default_rng(7)
+    n, groups = 3000, 16
+    ids = rng.integers(0, groups, size=n)
+    if case == "small":
+        vals = rng.integers(-50, 50, size=n)
+    elif case == "near_2_24":
+        ids = np.zeros(n, dtype=np.int64)
+        vals = rng.integers(1, 2**12, size=n)
+        vals[-1] += 2**24 - 1 - int(vals.sum())
+    elif case == "big":
+        vals = rng.integers(-2**40, 2**40, size=n)
+    elif case == "wide":
+        groups = ops._MAX_KERNEL_GROUPS + 1
+        ids = np.arange(n) % groups
+        vals = rng.integers(-50, 50, size=n)
+    elif case == "over":
+        vals = np.array([2**62, 2**62] + [1] * (n - 2), dtype=np.int64)
+    else:
+        vals = np.array([-2**63] + [0] * (n - 1), dtype=np.int64)
+    stats = {}
+    got = ops.grouped_reduce(vals, ids, groups, stats=stats)
+    assert stats == {path: 1}
+    if path == "device_fallbacks":
+        assert got is None
+    else:
+        assert got.dtype == np.int64
+        assert got.tolist() == _bigint_fold(vals, ids, groups)
+
+
+def test_grouped_reduce_empty_chunk_launches_nothing():
+    stats = {}
+    got = ops.grouped_reduce(np.array([], dtype=np.int64),
+                             np.array([], dtype=np.int64), 4, stats=stats)
+    assert got.tolist() == [0, 0, 0, 0] and stats == {}
+
+
+def test_grouped_reduce_compiles_a_bounded_set_of_shapes():
+    """34 chunks of row counts from 1 to 8192 and up to 300 groups compile
+    at most 4 row sizes x 3 group sizes of kernel programs."""
+    rng = np.random.default_rng(11)
+    before = ops.compiled_programs()["kernel"]
+    for n in list(rng.integers(1, 8193, size=30)) + [1, 1024, 1025, 8192]:
+        groups = int(rng.integers(1, 300))
+        ids = rng.integers(0, groups, size=n)
+        vals = rng.integers(-100, 100, size=n)
+        assert (ops.grouped_reduce(vals, ids, groups).tolist()
+                == _bigint_fold(vals, ids, groups))
+    assert ops.compiled_programs()["kernel"] - before <= 12
+
+
+def test_compile_cache_dir_follows_env_else_fixed_checkout_path(
+        monkeypatch, tmp_path):
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert ops.compile_cache_dir() == str(tmp_path)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    fixed = ops.compile_cache_dir()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert fixed == os.path.join(root, ".jax_cache")
+    assert ops.compile_cache_dir() == fixed

@@ -13,6 +13,7 @@ closures — so the only legal divergence is an exception."""
 import math
 import os
 import random
+import sys
 
 import numpy as np
 import pytest
@@ -402,6 +403,84 @@ def test_fusion_plants_mapbatches_in_the_lineage():
         return seen
     assert "mapbatches" in kinds(True)
     assert "mapbatches" not in kinds(False)
+
+
+# ------------------------------------------------------ jax device backend
+
+
+def _sql_workloads():
+    """The two SQL taxi queries chip_smoke.py runs on the chip."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmarks.shuffle_backends import SQL_WORKLOADS
+    return SQL_WORKLOADS
+
+
+@pytest.mark.parametrize("query", ["sql_filter_groupby", "sql_join_agg"])
+def test_jax_backend_taxi_queries_match_numpy_and_row_path(query):
+    """vector_backend="jax" sends the integer tip sums to bucket_reduce:
+    the answer equals the numpy backend's and the row path's exactly, the
+    kernel ran, and nothing went back to the host."""
+    from repro.data.synthetic import taxi_csv
+
+    data = taxi_csv(3000, seed=13)
+    fn = _sql_workloads()[query]
+    answers = {}
+    for leg, kw in [("jax", dict(vector_backend="jax")),
+                    ("numpy", dict(vector_backend="numpy")),
+                    ("row", dict(vectorize=False))]:
+        ctx = FlintContext(config=FlintConfig(concurrency=4, **kw))
+        ctx.upload("taxi.csv", data)
+        answers[leg] = sorted(fn(ctx))
+        if leg == "jax":
+            dev = ctx.last_scheduler.device_stats
+            assert dev["kernel_calls"] > 0
+            assert dev["device_fallbacks"] == 0 and dev["x64_sums"] == 0
+    _exact_rows(answers["jax"], answers["numpy"])
+    _exact_rows(answers["jax"], answers["row"])
+
+
+def test_jax_backend_hands_sums_past_2_62_back_to_host():
+    """Sums past the x64 envelope are the one documented hand-back: the
+    numpy path's bigint fold answers, and the task counts it."""
+    rows = [(i % 2, 2**61) for i in range(10)] + [(5, 3)]
+    out = {}
+    for vectorize in (True, False):
+        ctx = _mk_ctx(vectorize, vector_backend="jax")
+        df = ctx.parallelize(rows, 1).toDF([("k", "int"), ("v", "int")])
+        # the filter gives the fused operator a step to compile
+        out[vectorize] = sorted(
+            df.where(col("k") >= lit(0))
+            .groupBy("k").agg(sum_(col("v")).alias("s")).collect())
+        if vectorize:
+            dev = ctx.last_scheduler.device_stats
+            assert dev["device_fallbacks"] == 1 and dev["kernel_calls"] == 0
+    _exact_rows(out[True], out[False])
+    assert out[True][0] == (0, 5 * 2**61)
+
+
+@pytest.mark.parametrize("failure", ["runtime", "import"])
+def test_jax_backend_device_failure_raises(monkeypatch, failure):
+    """A device or import failure under vector_backend="jax" fails the
+    query; it is never re-run as a numpy or row-path answer."""
+    if failure == "runtime":
+        from repro.kernels import ops
+
+        def broken(*args, **kwargs):
+            raise RuntimeError("TPU device lost")
+        monkeypatch.setattr(ops, "grouped_reduce", broken)
+    else:
+        monkeypatch.setitem(sys.modules, "repro.kernels.ops", None)
+    ctx = _mk_ctx(True, vector_backend="jax")
+    ctx.upload("t.csv", _taxi_csv(50).encode())
+    df = ctx.read_csv("t.csv", TAXI, 2)
+    q = (df.withColumn("cents", (col("tip") * lit(100.0)).cast("int"))
+         .groupBy("payment").agg(sum_(col("cents")).alias("c")))
+    with pytest.raises(V.DeviceBackendError) as info:
+        q.collect()
+    cause = RuntimeError if failure == "runtime" else ImportError
+    assert isinstance(info.value.__cause__, cause)
 
 
 # ------------------------------------------------------------- chaos leg
