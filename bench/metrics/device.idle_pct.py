@@ -1,0 +1,9 @@
+"""Percent of the traced window in which no operation ran on the device
+(union of busy intervals, averaged over the chips used)."""
+
+
+def read(run):
+    tr = run["trace"]
+    if not tr or not tr["window_s"]:
+        return None
+    return 100.0 * (1.0 - tr["busy_s"] / tr["window_s"])
