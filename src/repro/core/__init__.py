@@ -46,6 +46,7 @@ from repro.core.queues import ObjectStoreSim
 from repro.core.rdd import RDD, ParallelCollection, Source
 from repro.core.cluster import ClusterScheduler
 from repro.core.scheduler import FlintScheduler, StageFailure
+from repro.core.spans import span
 
 
 class FlintContext:
@@ -125,7 +126,9 @@ class FlintContext:
         # lineage from source — bounded like any stage resubmission
         cache_replans_left = self.config.max_stage_retries
         while True:
-            plan = self._build_plan(rdd, action, save_prefix, mult, limit)
+            with span("flint.plan"):
+                plan = self._build_plan(rdd, action, save_prefix, mult,
+                                        limit)
             sched = self._make_scheduler()
             self.last_scheduler = sched
             try:
@@ -166,7 +169,8 @@ class FlintContext:
                     continue
                 raise
             finally:
-                sched.shutdown()
+                with span("flint.teardown"):
+                    sched.shutdown()
 
     def _build_plan(self, rdd, action, save_prefix, mult, limit):
         """Planning hook: the service session overrides this to thread

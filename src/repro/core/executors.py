@@ -40,7 +40,7 @@ import time
 import zlib
 from typing import Any
 
-from repro.core import serde
+from repro.core import serde, spans
 from repro.core.costs import (LAMBDA_PAYLOAD_LIMIT,
                               S3_EXCHANGE_BATCH_LIMIT, CostLedger)
 from repro.core.dag import (CacheInput, CollectionInput, ShuffleRead,
@@ -56,6 +56,10 @@ from repro.core.shuffle import (KVBatch, TransportSet, iter_records,
 from repro.core.shuffle.base import AbortedError  # noqa: F401 (re-export:
 #                       pre-subsystem callers import it from here)
 from repro.core.shuffle.base import LostShuffleInput
+from repro.core.spans import span
+
+#: the shuffle writer's span, held across the records of one run
+WRITE_SPAN = "flint.shuffle.write"
 
 
 class InjectedFailure(RuntimeError):
@@ -318,18 +322,16 @@ class LambdaSim:
         # the service; "j{n}/" per job under it, so the job-scoped GC can
         # sweep _payload/_result without touching other live jobs' keys)
         self.scope = ""
-        self.invocations = 0
-        self.cold_starts = 0
         self.throttles = 0
+        # the scheduler's job id, carried on every task span
+        self.job = 0
 
     def _acquire_container(self) -> bool:
         """Returns True on a cold start."""
         with self._lock:
-            self.invocations += 1
             if self._warm > 0:
                 self._warm -= 1
                 return False
-            self.cold_starts += 1
             return True
 
     def _release_container(self):
@@ -340,11 +342,15 @@ class LambdaSim:
         # the account-concurrency gauge counts this invocation from request
         # arrival (incremented BEFORE the admission check, so simultaneous
         # dispatches see each other) until the response is produced
-        running = self.gauge.enter()
-        try:
-            return self._invoke(payload, running)
-        finally:
-            self.gauge.exit()
+        with span("flint.task", job=self.job, stage=payload.get("stage", -1),
+                  task=payload.get("index", -1),
+                  attempt=payload.get("attempt", 0),
+                  dispatch=payload.get("dispatch", -1)):
+            running = self.gauge.enter()
+            try:
+                return self._invoke(payload, running)
+            finally:
+                self.gauge.exit()
 
     def _invoke(self, payload: dict, running: int) -> dict:
         if self.faults is not None:
@@ -519,7 +525,7 @@ def _drain_shuffle(read: ShuffleRead, env: LambdaSim, n_producers: dict, *,
     transport's DrainHandle; the per-producer EOS quorum comes from
     ``n_producers`` (fixed at plan time) in BOTH scheduler modes.
 
-    Returns ({(sid, mode): folded-aggregate}, stats, ack) where ``ack``
+    Returns ({(sid, mode): folded-aggregate}, ack) where ``ack``
     releases every drained input for good — the caller invokes it only
     once the task's output is durable (ack-after-fold), so an earlier
     death leaves the whole input to redeliver for the retry.
@@ -529,7 +535,6 @@ def _drain_shuffle(read: ShuffleRead, env: LambdaSim, n_producers: dict, *,
     attempts — sort them so the records this task re-emits are
     byte-identical and downstream (src, seq) dedup stays sound."""
     out = {}
-    stats = {"messages": 0, "duplicates": 0, "records": 0}
     combine = (serde.loads_fn(read.combine_fn)
                if isinstance(read.combine_fn, bytes) else read.combine_fn)
 
@@ -564,16 +569,14 @@ def _drain_shuffle(read: ShuffleRead, env: LambdaSim, n_producers: dict, *,
                                                             env.cfg))
         agg: Any = {} if mode in ("agg", "group", "join") else []
         for part in partitions:
-            handle = transport.open_drain(sid, part,
-                                          int(n_producers.get(str(sid), 0)),
-                                          group=claim_group,
-                                          consumer_group=consumer_group)
-            for _src, _seq, body in handle:
-                records = unpack_batch(body, env.rstore)
-                stats["records"] += len(records)
-                fold(agg, records, mode)
-            stats["messages"] += handle.stats["messages"]
-            stats["duplicates"] += handle.stats["duplicates"]
+            with span("flint.shuffle.drain") as drain:
+                handle = transport.open_drain(
+                    sid, part, int(n_producers.get(str(sid), 0)),
+                    group=claim_group, consumer_group=consumer_group)
+                for _src, _seq, body in handle:
+                    with span("flint.shuffle.fold"):
+                        fold(agg, unpack_batch(body, env.rstore), mode)
+                drain.set_metadata(duplicates=handle.state.duplicates)
             handles.append(handle)
         if sort_groups and mode in ("group", "join"):
             for vals in agg.values():
@@ -584,13 +587,13 @@ def _drain_shuffle(read: ShuffleRead, env: LambdaSim, n_producers: dict, *,
         for handle in handles:
             handle.ack()
 
-    return out, stats, ack
+    return out, ack
 
 
 def _shuffle_input_iter(read: ShuffleRead, env: LambdaSim,
                         n_producers: dict, *, sort_groups: bool = False):
-    data, stats, ack = _drain_shuffle(read, env, n_producers,
-                                      sort_groups=sort_groups)
+    data, ack = _drain_shuffle(read, env, n_producers,
+                               sort_groups=sort_groups)
     if read.self_join or len(read.parts) == 2:  # join
         if read.self_join:
             # CSE collapsed both sides onto one shared shuffle: the single
@@ -616,12 +619,12 @@ def _shuffle_input_iter(read: ShuffleRead, env: LambdaSim,
                     if k not in left:
                         for rv in rvals:
                             yield (k, (None, rv))
-        return it(), stats, ack
+        return it(), ack
     (sid, mode) = read.parts[0]
     agg = data[(sid, mode)]
     if mode in ("agg", "group"):
-        return iter(agg.items()), stats, ack
-    return iter(agg), stats, ack
+        return iter(agg.items()), ack
+    return iter(agg), ack
 
 
 def _flatmap_iter(it, fn):  # immediate fn binding (no late closure capture)
@@ -887,12 +890,12 @@ class _ShuffleWriter:
                 buf[k] = self.combine(buf[k], v) if k in buf else v
                 self.buffered += len(buf) - before
                 if self.buffered >= self.env.cfg.flush_records:
-                    self.flush()
+                    self._flush()
                 return
             self._append(p, record)
         self.buffered += 1
         if self.buffered >= self.env.cfg.flush_records:
-            self.flush()
+            self._flush()
 
     def _append(self, p: int, record):
         buf = self.buffers.get(p)
@@ -933,9 +936,28 @@ class _ShuffleWriter:
                 buf.extend(rows[i] for i in idxs)
         self.buffered += batch.n
         if self.buffered >= self.env.cfg.flush_records:
-            self.flush()
+            self._flush()
+
+    def add_all(self, records):
+        """Route every record (or KVBatch carrier) of ``records`` under
+        one held write span per stretch between upstream spans."""
+        held = spans.enabled()
+        try:
+            for rec in records:
+                if held:
+                    spans.hold(WRITE_SPAN)
+                if isinstance(rec, KVBatch):
+                    self.add_batch(rec)
+                else:
+                    self.add(rec)
+        finally:
+            spans.release()
 
     def flush(self):
+        with span(WRITE_SPAN):
+            self._flush()
+
+    def _flush(self):
         transport = self._transport()
         for p, buf in self.buffers.items():
             if isinstance(buf, _ColumnBuffer):
@@ -959,7 +981,9 @@ class _ShuffleWriter:
                                     columnar=self.env.cfg.columnar_batches,
                                     schema=self.write.batch_schema)
             seq = self.seq.get(p, 0)
-            transport.send(self.write.shuffle_id, p, self.src, seq, bodies)
+            with spans.keep_held(), span("flint.shuffle.send"):
+                transport.send(self.write.shuffle_id, p, self.src, seq,
+                               bodies)
             self.seq[p] = seq + len(bodies)
             st = self.out_stats.setdefault(p, [0, 0])
             st[0] += sum(len(b) for b in bodies)
@@ -975,8 +999,9 @@ class _ShuffleWriter:
         speculated duplicate re-emits identical EOS (partitioning and
         sequence assignment are deterministic), which consumers dedup by
         producer id."""
-        self._transport().emit_eos(self.write.shuffle_id, self.write.nparts,
-                                   self.src, self.seq)
+        with span(WRITE_SPAN), span("flint.shuffle.send"):
+            self._transport().emit_eos(self.write.shuffle_id,
+                                       self.write.nparts, self.src, self.seq)
 
 
 def executor_main(payload: dict, env: LambdaSim) -> dict:
@@ -1024,10 +1049,9 @@ def _run_task(payload: dict, env: LambdaSim, stats: dict) -> dict:
         base_iter = cache_partition_iter(inp, env.rstore)
         reader = None
     else:
-        base_iter, drain_stats, ack_shuffle = _shuffle_input_iter(
+        base_iter, ack_shuffle = _shuffle_input_iter(
             inp, env, payload.get("n_producers") or {},
             sort_groups=payload["write"] is not None)
-        stats.update(drain_stats)
         reader = None
 
     exhausted = {"flag": False}
@@ -1079,11 +1103,7 @@ def _run_task(payload: dict, env: LambdaSim, stats: dict) -> dict:
                 raise MemoryCapExceeded(
                     f"materialized shuffle output {len(out_iter)} records "
                     f"> cap {env.cfg.agg_memory_records}")
-        for rec in out_iter:
-            if isinstance(rec, KVBatch):
-                writer.add_batch(rec)
-            else:
-                writer.add(rec)
+        writer.add_all(out_iter)
         writer.flush()
         # per-link deltas: the scheduler sums links/attempts per shuffle
         stats["shuffle_out"] = {p: list(v)

@@ -53,6 +53,7 @@ cold start.
 from __future__ import annotations
 
 import concurrent.futures as cf
+import functools
 import heapq
 import itertools
 import pickle
@@ -71,6 +72,7 @@ from repro.core.faults import FaultInjector, FaultPlan
 from repro.core.queues import ObjectStoreSim, SQSSim
 from repro.core.retry import RetryBudget, TransientServiceError
 from repro.core.shuffle import TransportSet, pack_batch, unpack_batch
+from repro.core.spans import span
 
 #: transient object-store prefixes swept by the job-end GC (the S3
 #: exchange's _exchange/ prefix is swept by its transport's gc();
@@ -91,6 +93,12 @@ STREAM_PREFIX = "_stream/"
 #: while the task's shuffle identity (src = stage/index) stays unchanged,
 #: keeping the replay's re-emission byte-identical for downstream dedup
 _REPLAY_ATTEMPT = 1_000_000
+
+#: process-wide ids of jobs and of task dispatches, carried on their
+#: spans (a dispatch id also rides in the task's payload, so the task's
+#: span can be matched to the dispatch that sent it)
+_JOB_IDS = itertools.count(1)
+_DISPATCH_IDS = itertools.count(1)
 
 
 class StageFailure(RuntimeError):
@@ -205,6 +213,7 @@ class FlintScheduler:
                              gauge=(binding.gauge
                                     if binding is not None else None))
         self.lam.scope = self._scope
+        self.job = 0  # this scheduler's job id, set per run()
         self.pool = cf.ThreadPoolExecutor(max_workers=cfg.concurrency)
         self.verbose = verbose
         self.stage_stats: list[dict] = []
@@ -216,7 +225,8 @@ class FlintScheduler:
         # sums, x64 segment sums, and sums past 2**62 handed back to the
         # host's exact path — summed over successful task responses
         self.device_stats = {"kernel_calls": 0, "x64_sums": 0,
-                             "device_fallbacks": 0}
+                             "device_fallbacks": 0, "device_rows": 0,
+                             "device_padded_rows": 0}
         self._dispatch_sleep = 0.0  # decorrelated-jitter state, 0 = idle
         self._backoff_rng = random.Random(plan.seed ^ 0x5DEECE66D)
         self._stage_retries: dict[int, int] = {}  # stage idx -> resubmits
@@ -254,6 +264,11 @@ class FlintScheduler:
 
     # ------------------------------------------------------------------
     def run(self, stages: list[StagePlan]):
+        self.job = self.lam.job = next(_JOB_IDS)
+        with span("flint.job", job=self.job):
+            return self._run(stages)
+
+    def _run(self, stages: list[StagePlan]):
         self._stages = stages
         self._stage_done = [False] * len(stages)
         self._stage_retries = {}
@@ -715,6 +730,16 @@ class FlintScheduler:
         return result
 
     # ------------------------------------------------------------------
+    def _dispatch(self, submit, task: TaskDef, stage: StagePlan,
+                  attempt: int, extra: dict | None = None):
+        """Build the task's payload and hand it to ``submit``, under one
+        ``flint.dispatch`` span; returns the future."""
+        d = next(_DISPATCH_IDS)
+        with span("flint.dispatch", job=self.job, stage=stage.id,
+                  task=task.index, attempt=attempt, dispatch=d):
+            return submit(self._payload_for(task, stage, attempt,
+                                            dict(extra or {}, dispatch=d)))
+
     def _payload_for(self, task: TaskDef, stage: StagePlan, attempt: int,
                      extra: dict | None = None) -> dict:
         extra = dict(extra or {})
@@ -996,10 +1021,10 @@ class FlintScheduler:
             max_workers=max(1, cfg.concurrency // 2))
         try:
             def launch(task, extra=None):
-                payload = self._payload_for(
-                    task, stage, _REPLAY_ATTEMPT + attempts[task.index],
-                    dict(extra or {}))
-                inflight[pool.submit(self.lam.invoke, payload)] = task.index
+                fut = self._dispatch(
+                    functools.partial(pool.submit, self.lam.invoke), task,
+                    stage, _REPLAY_ATTEMPT + attempts[task.index], extra)
+                inflight[fut] = task.index
 
             for t in tasks:
                 launch(t)
@@ -1078,10 +1103,10 @@ class FlintScheduler:
         delayed: list = []  # (due, task, extra) — 429 dispatch backoff
 
         def launch(task: TaskDef, extra=None, speculative=False):
-            payload = self._payload_for(
+            fut = self._dispatch(
+                functools.partial(self.pool.submit, self._invoke_slotted),
                 task, stage, attempts[task.index],
                 dict(extra or {}, _speculative=speculative))
-            fut = self.pool.submit(self._invoke_slotted, payload)
             inflight[fut] = (task.index, speculative, time.monotonic())
 
         for task in stage.tasks:
@@ -1292,10 +1317,10 @@ class FlintScheduler:
                     continue  # stale: original already won
                 if stage_t0[si] is None:
                     stage_t0[si] = time.monotonic()
-                payload = self._payload_for(
+                fut = self._dispatch(
+                    functools.partial(self.pool.submit, self.lam.invoke),
                     task, stages[si], attempts[si][task.index],
                     dict(extra or {}, _speculative=speculative))
-                fut = self.pool.submit(self.lam.invoke, payload)
                 inflight[fut] = (si, task.index, speculative,
                                  time.monotonic())
             # advertise EFFECTIVE demand — what could launch right now.
@@ -1507,15 +1532,16 @@ class FlintScheduler:
     @staticmethod
     def _stage_result(stage: StagePlan, partials: dict) -> Any:
         n = len(stage.tasks)
-        if stage.action in ("collect", "sum"):
-            out = []
-            for i in range(n):
-                out.extend(partials.get(i, []))
-                if stage.limit is not None and len(out) >= stage.limit:
-                    # take(n): the merge short-circuits — later
-                    # partitions' results are never consumed
-                    return out[:stage.limit]
-            return sum(out) if stage.action == "sum" else out
+        with span("flint.merge"):
+            if stage.action in ("collect", "sum"):
+                out = []
+                for i in range(n):
+                    out.extend(partials.get(i, []))
+                    if stage.limit is not None and len(out) >= stage.limit:
+                        # take(n): the merge short-circuits — later
+                        # partitions' results are never consumed
+                        return out[:stage.limit]
+                return sum(out) if stage.action == "sum" else out
         if stage.action == "save":
             return [f"{stage.save_prefix}/part-{i:05d}" for i in range(n)]
         return None
@@ -1523,7 +1549,8 @@ class FlintScheduler:
     @staticmethod
     def _merge_partial(resp, idx, partials):
         if "result" in resp:
-            partials.setdefault(idx, []).extend(resp["result"])
+            with span("flint.merge"):
+                partials.setdefault(idx, []).extend(resp["result"])
 
     def gc_job(self) -> dict[str, int]:
         """Job-scoped garbage collection (idempotent): every transport

@@ -61,14 +61,14 @@ class DrainState:
     """Shared drain bookkeeping: (src, seq) dedup, per-producer counts, and
     the plan-time EOS quorum that terminates the drain."""
 
-    __slots__ = ("quorum", "seen", "per_src", "eos_total", "stats")
+    __slots__ = ("quorum", "seen", "per_src", "eos_total", "duplicates")
 
     def __init__(self, quorum: int):
         self.quorum = quorum
         self.seen: set = set()
         self.per_src: dict[str, int] = {}
         self.eos_total: dict[str, int] = {}
-        self.stats = {"messages": 0, "duplicates": 0}
+        self.duplicates = 0  # data batches dropped as already seen
 
     def register_eos(self, src: str, total: int) -> bool:
         """Record a producer's end-of-stream (total = its sequence count).
@@ -81,11 +81,10 @@ class DrainState:
     def register_data(self, src: str, seq: int) -> bool:
         """True if (src, seq) is fresh; duplicates are counted and dropped."""
         if (src, seq) in self.seen:
-            self.stats["duplicates"] += 1
+            self.duplicates += 1
             return False
         self.seen.add((src, seq))
         self.per_src[src] = self.per_src.get(src, 0) + 1
-        self.stats["messages"] += 1
         return True
 
     def done(self) -> bool:
@@ -99,13 +98,9 @@ class DrainState:
 class DrainHandle:
     """Iterator of fresh ``(src, seq, body)`` data batches for one
     (shuffle, partition). ``ack()`` is called by the executor only once the
-    task's output is durable; ``stats`` mirrors DrainState.stats."""
+    task's output is durable."""
 
     state: DrainState
-
-    @property
-    def stats(self) -> dict:
-        return self.state.stats
 
     def __iter__(self) -> Iterator:
         return self

@@ -46,6 +46,7 @@ from collections import deque
 from repro.core.costs import S3_EXCHANGE_BATCH_LIMIT
 from repro.core.shuffle.base import (AbortedError, DrainHandle, DrainState,
                                      LostShuffleInput, ShuffleTransport)
+from repro.core.spans import span
 
 EXCHANGE_PREFIX = "_exchange/"
 _TOMBSTONE = ".released-g"
@@ -388,7 +389,8 @@ class _S3Drain(DrainHandle):
             err2.detail = {"sid": self.sid,
                            "have_eos": sorted(self.state.eos_total)}
             raise err2
-        time.sleep(self._backoff)
+        with span("flint.shuffle.wait"):
+            time.sleep(self._backoff)
         self._backoff = min(self._backoff * 2, 0.1)
 
     def ack(self):

@@ -23,6 +23,7 @@ from repro.core.costs import SQS_BATCH_MESSAGES, SQS_MESSAGE_LIMIT
 from repro.core.queues import Message, QueueGone, eos_message
 from repro.core.shuffle.base import (AbortedError, DrainHandle, DrainState,
                                      ShuffleTransport)
+from repro.core.spans import span
 
 
 def queue_name(shuffle_id: int, partition: int, group: int = 0) -> str:
@@ -221,7 +222,8 @@ class _SQSDrain(DrainHandle):
             # claims to lapse — idle heartbeats on both sides split the
             # queue permanently. An idle drain instead re-receives its
             # claimed backlog each visibility period (re-billed, deduped).
-            sqs.wait_for_messages(self.name, 0.25)
+            with span("flint.shuffle.wait"):
+                sqs.wait_for_messages(self.name, 0.25)
             return
         self._want = None if len(msgs) == self._want else SQS_BATCH_MESSAGES
         progressed = False

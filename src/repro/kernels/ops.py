@@ -16,6 +16,7 @@ from pathlib import Path
 import jax
 import numpy as np
 
+from repro.core.spans import span
 from repro.kernels import ref
 from repro.kernels.bucket_reduce import bucket_reduce as _bucket_reduce
 from repro.kernels.flash_attention import flash_attention_bhsd
@@ -137,9 +138,9 @@ def compiled_programs() -> dict:
             "x64": _x64_sums._cache_size()}
 
 
-def _count(stats: dict | None, name: str) -> None:
+def _count(stats: dict | None, name: str, k: int = 1) -> None:
     if stats is not None:
-        stats[name] = stats.get(name, 0) + 1
+        stats[name] = stats.get(name, 0) + k
 
 
 def grouped_reduce(values, bucket_ids, n_buckets: int, *,
@@ -158,37 +159,45 @@ def grouped_reduce(values, bucket_ids, n_buckets: int, *,
         (the numpy engine falls back to Python bigint folds).
 
     Device errors propagate. ``stats``, when given, counts the path taken
-    (``kernel_calls`` / ``x64_sums`` / ``device_fallbacks``).
+    (``kernel_calls`` / ``x64_sums`` / ``device_fallbacks``), and the rows
+    a device program summed before and after padding (``device_rows`` /
+    ``device_padded_rows``). The call runs under a ``flint.grouped_sum``
+    span, its blocking fetch of the sums under ``flint.grouped_sum.fetch``.
     Returns a (n_buckets,) numpy int64 array, or None."""
-    vals = np.asarray(values, dtype=np.int64)
-    n = vals.shape[0]
-    if n == 0:
-        return np.zeros(n_buckets, dtype=np.int64)
-    # abs in float64: np.abs of int64 min wraps to a negative value
-    abs_sum = float(np.abs(vals.astype(np.float64)).sum())
-    if abs_sum > _X64_EXACT:
-        _count(stats, "device_fallbacks")
-        return None
-    interpret = _interpret() if interpret is None else interpret
-    if not interpret:
-        use_compile_cache()
-    rows = _pow2_at_least(n, _MIN_ROWS)
-    groups = _pow2_at_least(n_buckets, _MIN_GROUPS)
-    # pad rows carry value 0 and id -1: they land in no bucket
-    ids = np.full(rows, -1, dtype=np.int32)
-    ids[:n] = np.asarray(bucket_ids)
-    if abs_sum < _KERNEL_EXACT and groups <= _MAX_KERNEL_GROUPS:
-        padded = np.zeros(rows, dtype=np.float32)
-        padded[:n] = vals
-        out = _kernel_sums(padded, ids, groups, interpret)
-        _count(stats, "kernel_calls")
-    else:
-        padded = np.zeros(rows, dtype=np.int64)
-        padded[:n] = vals
-        with jax.enable_x64(True):
-            out = np.asarray(_x64_sums(padded, ids, groups))
-        _count(stats, "x64_sums")
-    return np.asarray(out, dtype=np.int64)[:n_buckets]
+    with span("flint.grouped_sum", rows=len(values), groups=n_buckets):
+        vals = np.asarray(values, dtype=np.int64)
+        n = vals.shape[0]
+        if n == 0:
+            return np.zeros(n_buckets, dtype=np.int64)
+        # abs in float64: np.abs of int64 min wraps to a negative value
+        abs_sum = float(np.abs(vals.astype(np.float64)).sum())
+        if abs_sum > _X64_EXACT:
+            _count(stats, "device_fallbacks")
+            return None
+        interpret = _interpret() if interpret is None else interpret
+        if not interpret:
+            use_compile_cache()
+        rows = _pow2_at_least(n, _MIN_ROWS)
+        groups = _pow2_at_least(n_buckets, _MIN_GROUPS)
+        # pad rows carry value 0 and id -1: they land in no bucket
+        ids = np.full(rows, -1, dtype=np.int32)
+        ids[:n] = np.asarray(bucket_ids)
+        if abs_sum < _KERNEL_EXACT and groups <= _MAX_KERNEL_GROUPS:
+            padded = np.zeros(rows, dtype=np.float32)
+            padded[:n] = vals
+            out = _kernel_sums(padded, ids, groups, interpret)
+            _count(stats, "kernel_calls")
+        else:
+            padded = np.zeros(rows, dtype=np.int64)
+            padded[:n] = vals
+            with jax.enable_x64(True):
+                out = _x64_sums(padded, ids, groups)
+            _count(stats, "x64_sums")
+        _count(stats, "device_rows", n)
+        _count(stats, "device_padded_rows", rows)
+        with span("flint.grouped_sum.fetch"):
+            out = np.asarray(out, dtype=np.int64)
+        return out[:n_buckets]
 
 
 def grouped_matmul(x, w, sizes=None, *, interpret: bool | None = None):

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 from typing import Iterable
 
+from repro.core.spans import span
 from repro.sql import plan as P
 from repro.sql.expr import (AggExpr, Alias, Col, Expr, Schema, _as_expr)
 from repro.sql.lower import apply_driver_ops, lower, vector_markers
@@ -223,10 +224,12 @@ class DataFrame:
         return optimize(self.plan, self.ctx) if optimize_flag else self.plan
 
     def collect(self, optimize: bool = True) -> list:
-        rdd, merge_limit, driver_ops = lower(self._planned(optimize),
-                                             self.ctx)
+        with span("flint.plan"):
+            rdd, merge_limit, driver_ops = lower(self._planned(optimize),
+                                                 self.ctx)
         rows = self.ctx.run_action(rdd, "collect", limit=merge_limit)
-        return apply_driver_ops(rows, driver_ops)
+        with span("flint.merge"):
+            return apply_driver_ops(rows, driver_ops)
 
     def take(self, n: int, optimize: bool = True) -> list:
         return self.limit(n).collect(optimize=optimize)

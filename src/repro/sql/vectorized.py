@@ -46,6 +46,7 @@ import itertools
 
 import numpy as np
 
+from repro.core import spans
 from repro.core.shuffle import KVBatch
 
 _NP_DTYPE = {"int": np.int64, "float": np.float64, "bool": np.bool_}
@@ -536,15 +537,6 @@ def _jax_int_sum(col, gids, ng):
 # ---------------------------------------------------------- fused operator
 
 
-def _chunks(it, size):
-    it = iter(it)
-    while True:
-        chunk = list(itertools.islice(it, size))
-        if not chunk:
-            return
-        yield chunk
-
-
 def make_fused(ingest, stages, emit, row_chain, batch_rows):
     """Build the batch-in/batch-out fused operator for RDD.mapBatches:
     chunk the partition iterator, run ingest -> stages -> emit per chunk
@@ -552,19 +544,28 @@ def make_fused(ingest, stages, emit, row_chain, batch_rows):
     (other than DeviceBackendError) through ``row_chain`` (the exact
     per-row closure pipeline for the same plan segment). Emissions are
     materialized per chunk BEFORE yielding so a mid-chunk fallback never
-    double-emits."""
+    double-emits. A chunk's pull, parse and operator work run under the
+    ``flint.scan``, ``flint.ingest`` and ``flint.fused`` spans."""
     def fused(it):
-        for chunk in _chunks(it, batch_rows):
+        it = iter(it)
+        while True:
+            with spans.span("flint.scan"):
+                chunk = list(itertools.islice(it, batch_rows))
+            if not chunk:
+                return
             try:
                 with np.errstate(divide="raise", invalid="raise",
                                  over="ignore", under="ignore"):
-                    cols, n = ingest(chunk)
-                    for stage in stages:
-                        cols, n = stage(cols, n)
-                    out = emit(cols, n)
+                    with spans.span("flint.ingest"):
+                        cols, n = ingest(chunk)
+                    with spans.span("flint.fused"):
+                        for stage in stages:
+                            cols, n = stage(cols, n)
+                        out = emit(cols, n)
             except DeviceBackendError:
                 raise
             except Exception:
-                out = list(row_chain(iter(chunk)))
+                with spans.span("flint.fused"):
+                    out = list(row_chain(iter(chunk)))
             yield from out
     return fused

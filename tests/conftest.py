@@ -137,3 +137,40 @@ def make_batch(cfg, key, batch=2, seq=16):
     if cfg.is_enc_dec:
         out["enc_embeds"] = jax.random.normal(key, (batch, 8, cfg.d_model))
     return out
+
+
+@pytest.fixture
+def program_trace(tmp_path):
+    """``record(fn)`` runs ``fn`` under the profiler with the engine's spans
+    switched on from after ``start_trace`` to before ``stop_trace``, and
+    returns (fn's result, the ``flint.*`` host events as (name, start_ns,
+    end_ns, thread line, stats dict))."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    from repro.core import spans
+
+    def record(fn):
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 1
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        spans.enable(True)
+        try:
+            out = fn()
+        finally:
+            spans.enable(False)
+            jax.profiler.stop_trace()
+        path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                          recursive=True)
+        events = []
+        for plane in ProfileData.from_file(path).planes:
+            if plane.name.startswith("/host:"):
+                for i, line in enumerate(plane.lines):
+                    events.extend(
+                        (e.name, e.start_ns, e.start_ns + e.duration_ns,
+                         f"{plane.name}#{i}", dict(e.stats))
+                        for e in line.events if e.name.startswith("flint."))
+        return out, events
+    return record

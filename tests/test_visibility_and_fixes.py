@@ -303,9 +303,9 @@ def test_consumer_retry_when_attempt_holds_messages_in_flight():
     sqs.send_batch(q7, [Message(b"", 1, "s0t0", kind="eos")])
 
     read = ShuffleRead([(7, "group")], 0)
-    out1, _, _ack1 = _drain_shuffle(read, env, {"7": 1})
+    out1, _ack1 = _drain_shuffle(read, env, {"7": 1})
     # first attempt "dies" here: _ack1 never called, messages in flight
-    out2, _, ack2 = _drain_shuffle(read, env, {"7": 1})
+    out2, ack2 = _drain_shuffle(read, env, {"7": 1})
     assert out1[(7, "group")] == out2[(7, "group")]
     ack2()
     assert sqs.inflight_len(q7) == 0
