@@ -516,24 +516,24 @@ def _read_transport_name(read: ShuffleRead, sid: int, cfg: FlintConfig
     return (read.transports or {}).get(sid) or cfg.fallback_backend
 
 
-def _drain_shuffle(read: ShuffleRead, env: LambdaSim, n_producers: dict, *,
-                   sort_groups: bool = False) -> tuple:
+def _drain_shuffle(read: ShuffleRead, env: LambdaSim, n_producers: dict
+                   ) -> tuple:
     """Drain this partition's shuffle input(s) through their transports,
     folding each record batch into the aggregate AS IT ARRIVES (streaming —
-    transport time overlaps the fold). Termination, dedup of at-least-once
-    unordered delivery, claim leases and abort detection all live in the
-    transport's DrainHandle; the per-producer EOS quorum comes from
-    ``n_producers`` (fixed at plan time) in BOTH scheduler modes.
+    transport time overlaps the fold); group/join value-lists are appended
+    in (src, seq) order once the drain ends. Termination, dedup of
+    at-least-once unordered delivery, claim leases and abort detection all
+    live in the transport's DrainHandle; the per-producer EOS quorum comes
+    from ``n_producers`` (fixed at plan time) in BOTH scheduler modes.
 
     Returns ({(sid, mode): folded-aggregate}, ack) where ``ack``
     releases every drained input for good — the caller invokes it only
     once the task's output is durable (ack-after-fold), so an earlier
     death leaves the whole input to redeliver for the retry.
 
-    ``sort_groups`` (set when this task WRITES another shuffle): group/
-    join value-lists collect in arrival order, which differs across
-    attempts — sort them so the records this task re-emits are
-    byte-identical and downstream (src, seq) dedup stays sound."""
+    The (src, seq) order makes value-lists independent of how producers'
+    sends interleaved: every attempt of a task that re-emits them emits
+    byte-identical records, as downstream (src, seq) dedup needs."""
     out = {}
     combine = (serde.loads_fn(read.combine_fn)
                if isinstance(read.combine_fn, bytes) else read.combine_fn)
@@ -573,14 +573,21 @@ def _drain_shuffle(read: ShuffleRead, env: LambdaSim, n_producers: dict, *,
                 handle = transport.open_drain(
                     sid, part, int(n_producers.get(str(sid), 0)),
                     group=claim_group, consumer_group=consumer_group)
-                for _src, _seq, body in handle:
+                held = []  # group/join batches, appended in (src, seq) order
+                for src, seq, body in handle:
                     with span("flint.shuffle.fold"):
-                        fold(agg, unpack_batch(body, env.rstore), mode)
+                        records = unpack_batch(body, env.rstore)
+                        if mode in ("group", "join"):
+                            held.append((src, seq, records))
+                        else:
+                            fold(agg, records, mode)
+                if held:
+                    with span("flint.shuffle.fold"):
+                        held.sort(key=lambda batch: batch[:2])
+                        for _src, _seq, records in held:
+                            fold(agg, records, mode)
                 drain.set_metadata(duplicates=handle.state.duplicates)
             handles.append(handle)
-        if sort_groups and mode in ("group", "join"):
-            for vals in agg.values():
-                vals.sort(key=_stable_order)
         out[(sid, mode)] = agg
 
     def ack():
@@ -591,9 +598,8 @@ def _drain_shuffle(read: ShuffleRead, env: LambdaSim, n_producers: dict, *,
 
 
 def _shuffle_input_iter(read: ShuffleRead, env: LambdaSim,
-                        n_producers: dict, *, sort_groups: bool = False):
-    data, ack = _drain_shuffle(read, env, n_producers,
-                               sort_groups=sort_groups)
+                        n_producers: dict):
+    data, ack = _drain_shuffle(read, env, n_producers)
     if read.self_join or len(read.parts) == 2:  # join
         if read.self_join:
             # CSE collapsed both sides onto one shared shuffle: the single
@@ -1050,8 +1056,7 @@ def _run_task(payload: dict, env: LambdaSim, stats: dict) -> dict:
         reader = None
     else:
         base_iter, ack_shuffle = _shuffle_input_iter(
-            inp, env, payload.get("n_producers") or {},
-            sort_groups=payload["write"] is not None)
+            inp, env, payload.get("n_producers") or {})
         reader = None
 
     exhausted = {"flag": False}

@@ -223,10 +223,14 @@ class FlintScheduler:
                                "stage_resubmits": 0, "replayed_tasks": 0}
         # device backend (vector_backend="jax"): compiled-kernel grouped
         # sums, x64 segment sums, and sums past 2**62 handed back to the
-        # host's exact path — summed over successful task responses
+        # host's exact path; and the scan chunks the fused operator parsed
+        # column-wise or line by line — summed over successful task
+        # responses
         self.device_stats = {"kernel_calls": 0, "x64_sums": 0,
                              "device_fallbacks": 0, "device_rows": 0,
-                             "device_padded_rows": 0}
+                             "device_padded_rows": 0,
+                             "ingest_columnar_chunks": 0,
+                             "ingest_line_chunks": 0}
         self._dispatch_sleep = 0.0  # decorrelated-jitter state, 0 = idle
         self._backoff_rng = random.Random(plan.seed ^ 0x5DEECE66D)
         self._stage_retries: dict[int, int] = {}  # stage idx -> resubmits

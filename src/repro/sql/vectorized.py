@@ -48,6 +48,7 @@ import numpy as np
 
 from repro.core import spans
 from repro.core.shuffle import KVBatch
+from repro.sql import csvscan
 
 _NP_DTYPE = {"int": np.int64, "float": np.float64, "bool": np.bool_}
 _NUMERIC = ("int", "float")
@@ -314,22 +315,28 @@ def _compile_cast(expr, schema):
 
 def scan_ingest(specs):
     """Vectorized CSV parse: ``specs`` is [(field_idx, dtype, cast_fn)]
-    per pruned output column. Parsing itself is the exact Python cast
-    (int()/float()/bool-parse per field) collected straight into arrays —
-    C-speed collection, Python-identical values."""
+    per pruned output column. An ASCII chunk whose lines all hold the same
+    number of fields parses column-wise from one byte buffer; any other
+    chunk takes the per-line parse (``repro.sql.csvscan``). Both give
+    Python-identical values. The running task counts the chunks each path
+    took (``ingest_columnar_chunks`` / ``ingest_line_chunks``)."""
+    width = max(idx for idx, _, _ in specs) + 1 if specs else 0
+
     def ingest(lines):
-        parts = [ln.split(",") for ln in lines]
-        n = len(parts)
-        cols = []
-        for idx, dtype, cast in specs:
-            raw = [p[idx] for p in parts]
-            if dtype == "str":
-                cols.append([cast(r) for r in raw])
-            else:
-                cols.append(np.fromiter(map(cast, raw),
-                                        dtype=_NP_DTYPE[dtype], count=n))
-        return cols, n
+        cols = csvscan.parse_columnar(lines, specs, width)
+        if cols is None:
+            _count_chunk("ingest_line_chunks")
+            return csvscan.parse_lines(lines, specs), len(lines)
+        _count_chunk("ingest_columnar_chunks")
+        return cols, len(lines)
     return ingest
+
+
+def _count_chunk(name: str) -> None:
+    from repro.core.executors import task_stats
+    stats = task_stats()
+    if stats is not None:
+        stats[name] = stats.get(name, 0) + 1
 
 
 def rows_ingest(dtypes):

@@ -244,3 +244,20 @@ def test_pipelined_cost_report_still_pay_as_you_go():
     # "auto" default: the planner resolves the transport per shuffle
     shuffle_requests = rep["sqs_requests"] + rep["s3_lists"]
     assert shuffle_requests > 0 and rep["total_usd"] > 0
+
+
+def test_group_lists_follow_producer_order_not_arrival_order():
+    """groupByKey value-lists are built in (src, seq) order: the first
+    partition's producer finishing last changes nothing."""
+    import time
+
+    def slow_first(x):
+        if x < 100:
+            time.sleep(0.0005)
+        return (x % 3, x)
+
+    ctx = FlintContext("flint", FlintConfig(concurrency=8))
+    got = dict(ctx.parallelize(range(400), 4).map(slow_first)
+               .groupByKey(2).collect())
+    assert got == {k: [x for x in range(400) if x % 3 == k]
+                   for k in range(3)}
