@@ -49,7 +49,8 @@ def _typed(rows) -> list:
 def check_kernel_exactness(seed: int) -> None:
     """Per-chunk sums just under 2**24 with values that need all 24 bits
     of an f32 mantissa: any bf16 rounding in the MXU passes shows here.
-    One more case checks the x64 path past that envelope."""
+    Two more cases check the limb sums past that envelope and the x64
+    path past the kernel's groups."""
     import numpy as np
 
     from repro.kernels import ops
@@ -64,17 +65,18 @@ def check_kernel_exactness(seed: int) -> None:
     one = rng.integers(1, 2**12, 5000)
     one[-1] += (2**24 - 1) - int(one.sum())
     cases.append((one, np.zeros(5000, dtype=np.int64), 1))
-    # past the kernel's envelope: the x64 segment sum (int64 on the chip)
+    # past 2**24: 11-bit limbs of both signs on the same kernel
     big = rng.integers(-(2**40), 2**40, 8192)
     cases.append((big, rng.integers(0, 24, 8192), 24))
+    # past the kernel's groups: the x64 segment sum (int64 on the chip)
+    cases.append((big, np.arange(8192) % 8200, 8200))
     for vals, ids, groups in cases:
-        path = ("kernel_calls" if np.abs(vals).sum() < 2**24
-                else "x64_sums")
+        path = "kernel_calls" if groups <= 8192 else "x64_sums"
         stats: dict = {}
         got = ops.grouped_reduce(vals, ids, groups, stats=stats)
         want = np.zeros(groups, dtype=np.int64)
         np.add.at(want, ids, vals)
-        if stats != {path: 1}:
+        if stats.get(path) != 1 or "device_fallbacks" in stats:
             raise SmokeFailure(f"exactness case took {stats}, not {path}")
         if not np.array_equal(got, want):
             bad = np.flatnonzero(got != want)

@@ -173,6 +173,8 @@ def _unpack_function(packed: dict, memo: dict) -> types.FunctionType:
 # grammar:
 #
 #   "i"  int64        "f"  float64      "b"  bool
+#   "d<s>"  a decimal of scale s, held as its unscaled int64 ("d2")
+#   "D"  a date, held as its int32 day number since 1970-01-01
 #   "s"  utf-8 string (u16 length prefixes; "S" when any string is >64 KiB)
 #   "t(a,b,...)"  fixed-arity tuple of columns, recursively
 #   "l(a)"  ragged lists with a homogeneous element type (u32 per-value
@@ -181,10 +183,21 @@ def _unpack_function(packed: dict, memo: dict) -> types.FunctionType:
 #           downstream ride this instead of falling back to pickle framing.
 #
 # Anything else (mixed types, ints beyond int64, None, ...) has no schema;
-# the batch falls back to length-prefixed pickle framing.
+# the batch falls back to length-prefixed pickle framing. "d<s>" and "D"
+# are only declared (the SQL layer's decimal and date columns hold plain
+# ints), never sniffed: the scale rides in the schema, so a body says in
+# what unit its ints count.
 
 _INT64_MIN, _INT64_MAX = -(2**63), 2**63 - 1
+_INT32_MIN, _INT32_MAX = -(2**31), 2**31 - 1
 _U32 = struct.Struct("<I")
+
+
+def _int_code(schema: str) -> str | None:
+    """The struct code of an int-held column schema, else None."""
+    if schema == "i" or (schema[:1] == "d" and schema[1:].isdigit()):
+        return "q"
+    return "i" if schema == "D" else None
 
 
 def column_schema(values: list) -> str | None:
@@ -231,9 +244,11 @@ def column_conforms(schema: str, values: list) -> bool:
     a declared-schema encode must verify concrete types first — the wire
     round-trips values exactly or not at all (mismatch => the caller
     falls back to sniffing)."""
-    if schema == "i":
-        return all(type(v) is int and _INT64_MIN <= v <= _INT64_MAX
-                   for v in values)
+    code = _int_code(schema)
+    if code is not None:
+        lo, hi = ((_INT64_MIN, _INT64_MAX) if code == "q"
+                  else (_INT32_MIN, _INT32_MAX))
+        return all(type(v) is int and lo <= v <= hi for v in values)
     if schema == "f":
         return all(type(v) is float for v in values)
     if schema == "b":
@@ -279,8 +294,9 @@ def _split_tuple_schema(schema: str) -> list[str]:
 
 def encode_column(schema: str, values: list) -> bytes:
     n = len(values)
-    if schema == "i":
-        return struct.pack("<%dq" % n, *values)
+    code = _int_code(schema)
+    if code is not None:
+        return struct.pack("<%d%s" % (n, code), *values)
     if schema == "f":
         return struct.pack("<%dd" % n, *values)
     if schema == "b":
@@ -307,8 +323,9 @@ def encode_column(schema: str, values: list) -> bytes:
 
 
 def decode_column(schema: str, blob: bytes, n: int) -> list:
-    if schema == "i":
-        return list(struct.unpack("<%dq" % n, blob))
+    code = _int_code(schema)
+    if code is not None:
+        return list(struct.unpack("<%d%s" % (n, code), blob))
     if schema == "f":
         return list(struct.unpack("<%dd" % n, blob))
     if schema == "b":
@@ -348,7 +365,10 @@ def column_value_sizes(schema: str, values: list) -> list[int]:
     """Exact encoded bytes per value (framing prefixes excluded) — lets the
     batch packer split a column set under a byte cap without encoding
     speculative chunks."""
-    if schema == "i" or schema == "f":
+    code = _int_code(schema)
+    if code is not None:
+        return [8 if code == "q" else 4] * len(values)
+    if schema == "f":
         return [8] * len(values)
     if schema == "b":
         return [1] * len(values)

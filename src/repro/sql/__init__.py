@@ -18,16 +18,20 @@ unchanged. See docs/dataframe.md.
        .groupBy("hour")
        .agg(sum_(col("tip")).alias("tips"), count_().alias("n"))
        .collect())
+
+Columns may be "date" and exact "decimal(p,s)"; ``lit`` takes
+``datetime.date`` and ``decimal.Decimal``, ``interval(n, unit)`` shifts a
+date, and ``df.agg(...)`` is a global aggregate (docs/dataframe.md).
 """
 
 from repro.sql.dataframe import DataFrame, GroupedData
 from repro.sql.expr import (AggExpr, Alias, BinOp, Col, Expr, Lit, Schema,
-                            avg_, col, collect_list, count_, lit, max_,
-                            min_, sum_, udf)
+                            avg_, col, collect_list, count_, interval, lit,
+                            max_, min_, sum_, udf)
 from repro.sql.optimizer import optimize
 from repro.sql.plan import explain_str
 
-__all__ = ["DataFrame", "GroupedData", "Schema", "col", "lit", "udf",
-           "sum_", "count_", "min_", "max_", "avg_", "collect_list",
+__all__ = ["DataFrame", "GroupedData", "Schema", "col", "lit", "interval",
+           "udf", "sum_", "count_", "min_", "max_", "avg_", "collect_list",
            "optimize", "explain_str", "Expr", "Col", "Lit", "Alias",
            "BinOp", "AggExpr"]

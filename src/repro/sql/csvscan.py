@@ -2,15 +2,19 @@
 operator (``sql/vectorized.py:scan_ingest``, docs/vectorized_execution.md).
 
 ``specs`` is [(field_idx, dtype, cast)] per pruned output column, ``cast``
-the exact Python cast of the dtype (``int``, ``float``, ``str``, or the
-bool parse). Columns come back as the vectorized path holds them: int /
-float / bool as numpy int64 / float64 / bool arrays, str as a list.
+the exact Python cast of the dtype (``int``, ``float``, ``str``, the bool
+parse, or ``repro.sql.expr.scan_cast`` of a date or decimal). Columns
+come back as the vectorized path holds them: int / float / bool as numpy
+int64 / float64 / bool arrays, a date or decimal as an int64 array of
+day numbers or unscaled ints, str as a list.
 
 * ``parse_lines`` splits every line and casts each pruned field with its
   Python cast: the per-line parse, for any chunk.
 * ``parse_columnar`` joins an ASCII chunk whose lines all hold the same
   number of fields into one byte buffer, finds every separator at once,
-  and reads int and float fields a whole column at a time. It gives the
+  and reads int, float and decimal fields, and ``YYYY-MM-DD`` dates, a
+  whole column at a time; a decimal goes straight from its digits to its
+  unscaled int, with no float in between. It gives the
   per-line parse's values bit for bit: a field outside the strict decimal
   forms goes through its Python cast, on the same text, in row order, so
   it raises what the per-line parse raises. Other chunks return None.
@@ -26,8 +30,11 @@ import itertools
 
 import numpy as np
 
-_NP_DTYPE = {"int": np.int64, "float": np.float64, "bool": np.bool_}
-_COMMA, _NEWLINE, _MINUS = b",\n-"
+from repro.sql.expr import decimal_scale
+
+_NP_DTYPE = {"int": np.int64, "float": np.float64, "bool": np.bool_,
+             "date": np.int64}
+_COMMA, _NEWLINE, _MINUS, _DASH = b",\n--"
 
 #: the longest field, minus its sign, that the column-wise number parse
 #: reads: 18 characters, so a field's digits always fit an int64
@@ -61,6 +68,10 @@ _DIGIT_WEIGHTS = np.stack(
      np.where(_PLACES >= _HALF, 10.0 ** (_PLACES - _HALF), 0)], axis=1)
 
 
+def _np_dtype(dtype: str):
+    return np.int64 if decimal_scale(dtype) is not None else _NP_DTYPE[dtype]
+
+
 def parse_lines(lines, specs) -> list:
     """Split every line, then cast each pruned field with its Python
     cast, collected straight into arrays."""
@@ -73,7 +84,7 @@ def parse_lines(lines, specs) -> list:
             cols.append([cast(r) for r in raw])
         else:
             cols.append(np.fromiter(map(cast, raw),
-                                    dtype=_NP_DTYPE[dtype], count=n))
+                                    dtype=_np_dtype(dtype), count=n))
     return cols
 
 
@@ -90,16 +101,22 @@ def parse_columnar(lines, specs, width: int) -> list | None:
         start, end = bounds(idx)
         if dtype in ("int", "float"):
             col, slow = _parse_numbers(buf, start, end, dtype == "float")
-            if slow.size:  # Python casts, in row order as per line
-                col[slow] = np.fromiter(
-                    map(cast, _substrings(text, start[slow], end[slow])),
-                    dtype=col.dtype, count=slow.size)
-            cols.append(col)
+        elif (scale := decimal_scale(dtype)) is not None:
+            col, slow = _parse_numbers(buf, start, end, True, scale)
+        elif dtype == "date":
+            col, slow = _parse_dates(buf, start, end)
         elif dtype == "str":
             cols.append(_substrings(text, start, end))
+            continue
         else:
             cols.append(np.fromiter(map(cast, _substrings(text, start, end)),
                                     dtype=_NP_DTYPE[dtype], count=len(start)))
+            continue
+        if slow.size:  # Python casts, in row order as per line
+            col[slow] = np.fromiter(
+                map(cast, _substrings(text, start[slow], end[slow])),
+                dtype=col.dtype, count=slow.size)
+        cols.append(col)
     return cols
 
 
@@ -141,12 +158,15 @@ def _substrings(text, start, end) -> list:
     return [text[a:b] for a, b in zip(start.tolist(), end.tolist())]
 
 
-def _parse_numbers(buf, start, end, is_float):
+def _parse_numbers(buf, start, end, is_float, scale=None):
     """Parse the fields ``buf[start:end]`` of one column at once. The
     strict forms are ``-?[0-9]+`` for ints and ``-?[0-9]*\\.?[0-9]*`` with
-    a digit for floats, at most ``_MAX_DIGITS`` characters after the sign;
-    a float's mantissa must also lie below 2**53. Returns (values, slow):
-    the rows in ``slow`` break a bound and hold no value yet."""
+    a digit for floats and decimals, at most ``_MAX_DIGITS`` characters
+    after the sign; a float's mantissa must also lie below 2**53, and a
+    decimal may have at most ``scale`` digits after its point and at most
+    ``_MAX_DIGITS`` digits once scaled; ``scale`` is given for a decimal
+    only. Returns (values, slow): the rows in ``slow`` break a bound and
+    hold no value yet."""
     lens = end - start
     w = int(min(lens.max(), _MAX_DIGITS + 1))
     # the last w bytes of each field, right-aligned: the bytes left of a
@@ -171,9 +191,50 @@ def _parse_numbers(buf, start, end, is_float):
         # drop that 0: with L the digits left of it, m - 9 L 10**k
         left = np.where(point, m // _POW10[k + 1], 0)
         m -= 9 * left * _POW10[k]
-        ok &= m < _EXACT_MANTISSA
-        vals = m / _POW10_F64[k]
+        if scale is None:
+            ok &= m < _EXACT_MANTISSA
+            vals = m / _POW10_F64[k]
+        else:  # decimal: the digits times 10**(scale - k), exact
+            digits = body - point
+            ok &= (k <= scale) & (digits + scale - k <= _MAX_DIGITS)
+            vals = m * _POW10[np.where(ok, scale - k, 0)]
     else:
         vals = m
     np.negative(vals, out=vals, where=signed)
+    return vals, np.flatnonzero(~ok)
+
+
+#: days before each month of a common year, and the days in it
+_MONTH_START = np.array([0, 31, 59, 90, 120, 151, 181, 212, 243, 273, 304,
+                         334])
+_MONTH_DAYS = np.array([31, 28, 31, 30, 31, 30, 31, 31, 30, 31, 30, 31])
+_DATE_DIGITS = np.array([0, 1, 2, 3, 5, 6, 8, 9])
+#: days from 0001-01-01 to 1970-01-01
+_EPOCH = 719162
+
+
+def _parse_dates(buf, start, end):
+    """Day numbers (days since 1970-01-01) of the ``YYYY-MM-DD`` fields
+    of one column at once; (values, slow) as ``_parse_numbers``, a field
+    of any other form or no such day being slow."""
+    n = start.size
+    vals = np.zeros(n, dtype=np.int64)
+    ok = (end - start) == 10
+    rows = np.flatnonzero(ok)
+    field = buf.take(start[rows, None] + np.arange(10))
+    d = field[:, _DATE_DIGITS].astype(np.int64) - 48
+    good = ((d >= 0) & (d <= 9)).all(axis=1)
+    good &= (field[:, 4] == _DASH) & (field[:, 7] == _DASH)
+    y = d[:, 0] * 1000 + d[:, 1] * 100 + d[:, 2] * 10 + d[:, 3]
+    m = d[:, 4] * 10 + d[:, 5]
+    day = d[:, 6] * 10 + d[:, 7]
+    good &= (y >= 1) & (m >= 1) & (m <= 12)
+    mi = np.clip(m - 1, 0, 11)
+    leap = (y % 4 == 0) & ((y % 100 != 0) | (y % 400 == 0))
+    good &= (day >= 1) & (day <= _MONTH_DAYS[mi] + (leap & (mi == 1)))
+    py = y - 1
+    vals[rows] = (py * 365 + py // 4 - py // 100 + py // 400
+                  + _MONTH_START[mi] + (leap & (mi >= 2)) + day - 1
+                  - _EPOCH)
+    ok[rows] = good
     return vals, np.flatnonzero(~ok)

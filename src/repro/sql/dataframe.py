@@ -10,15 +10,18 @@
              .collect())                            # list of tuples
     print(df.explain())                             # optimized plan tree
 
-Rows are plain tuples in schema order. ``collect``/``count``/``explain``
-take ``optimize=False`` to run the naive lowering — the benchmark's A/B
-baseline. ``limit`` is a FINAL operator: after it, only more
-orderBy/limit/actions may follow (the lowering splits the root chain
-between per-partition ops and a driver finish). ``orderBy`` keeps the
-frame open: under ``FlintConfig.adaptive`` it executes as a distributed
-range-partitioned sort wherever it sits in the plan
-(docs/adaptive_execution.md); without adaptive, a root orderBy falls
-back to the driver-side sort of the collected rows.
+Rows are plain tuples in schema order; "date" columns come back as
+``datetime.date`` and "decimal(p,s)" columns as ``decimal.Decimal``.
+``groupBy()`` with no keys, or ``df.agg(...)``, is a global aggregate:
+one row, even over no rows (count 0, every other aggregate NULL).
+``collect``/``count``/``explain`` take ``optimize=False`` to run the
+naive lowering — the benchmark's A/B baseline. ``limit`` is a FINAL
+operator: after it, only more orderBy/limit/actions may follow (the
+lowering splits the root chain between per-partition ops and a driver
+finish). ``orderBy`` keeps the frame open: under ``FlintConfig.adaptive``
+it executes as a distributed range-partitioned sort wherever it sits in
+the plan (docs/adaptive_execution.md); without adaptive, a root orderBy
+falls back to the driver-side sort of the collected rows.
 """
 
 from __future__ import annotations
@@ -27,7 +30,8 @@ from typing import Iterable
 
 from repro.core.spans import span
 from repro.sql import plan as P
-from repro.sql.expr import (AggExpr, Alias, Col, Expr, Schema, _as_expr)
+from repro.sql.expr import (AggExpr, Alias, Col, Expr, Schema, _as_expr,
+                            surface_fn)
 from repro.sql.lower import apply_driver_ops, lower, vector_markers
 from repro.sql.optimizer import optimize
 
@@ -141,11 +145,14 @@ class DataFrame:
                                      name))
 
     def groupBy(self, *keys) -> GroupedData:
+        """Group by ``keys``; with none, the aggregate is global."""
         self._require_open("groupBy")
-        if not keys:
-            raise ValueError("groupBy() needs at least one key")
         named = tuple(_named(k, "groupBy") for k in keys)
         return GroupedData(self, named)
+
+    def agg(self, *aggs: AggExpr, **kw) -> "DataFrame":
+        """A global aggregate: ``groupBy().agg(...)``."""
+        return self.groupBy().agg(*aggs, **kw)
 
     def join(self, other: "DataFrame", on, numPartitions: int | None = None,
              how: str = "inner", transport: str | None = None
@@ -229,7 +236,9 @@ class DataFrame:
                                                  self.ctx)
         rows = self.ctx.run_action(rdd, "collect", limit=merge_limit)
         with span("flint.merge"):
-            return apply_driver_ops(rows, driver_ops)
+            rows = apply_driver_ops(rows, driver_ops)
+            surface = surface_fn(self.plan.schema())
+            return rows if surface is None else [surface(r) for r in rows]
 
     def take(self, n: int, optimize: bool = True) -> list:
         return self.limit(n).collect(optimize=optimize)

@@ -1,11 +1,24 @@
 """Schemas and the expression language for the DataFrame surface.
 
-A ``Schema`` is an ordered list of (name, dtype) fields; dtypes are the
-four scalar types the columnar wire format speaks natively ("int",
-"float", "str", "bool") plus "list:<dtype>" for collect_list outputs.
-Because the schema is declared, every lowered shuffle ships
-schema-declared typed columnar batches (core.shuffle.batch) instead of
-sniffing types per batch.
+A ``Schema`` is an ordered list of (name, dtype) fields. The scalar
+dtypes are "int", "float", "str", "bool", "date" and "decimal(p,s)",
+plus "list:<dtype>" for collect_list outputs. Because the schema is
+declared, every lowered shuffle ships schema-declared typed columnar
+batches (core.shuffle.batch) instead of sniffing types per batch.
+
+Dates and decimals are exact integers inside the engine: a "date" is
+the int count of days since 1970-01-01, and a "decimal(p,s)" is its
+unscaled int (``21168.23`` at scale 2 is 2116823). Every operator, both
+execution paths and the wire work on those ints; ``collect()`` surfaces
+them as ``datetime.date`` and ``decimal.Decimal`` (``surface_fn``).
+Decimal arithmetic follows Spark's scale rules: ``+``/``-`` take
+max(s1, s2), ``*`` takes s1 + s2, an int counts as scale 0, and ``/``
+gives a float; precision is max(p1 - s1, p2 - s2) + s + 1 for ``+``/``-``
+and p1 + p2 + 1 for ``*``, capped at 38, and is not enforced on values.
+A decimal never meets a float without an explicit ``cast``. A date plus
+or minus an ``interval`` of days, months or years is a date (months
+clamp to the month's last day); literal dates, intervals and decimals
+fold when the expression is built.
 
 Expressions are small trees (``col``, ``lit``, arithmetic / comparison /
 boolean operators, ``substr``, ``cast``, ``udf``) that know three things:
@@ -35,18 +48,201 @@ semantics.
 
 from __future__ import annotations
 
+import calendar
+import datetime
+import decimal
 import operator
+import re
 from typing import Callable, Iterable
 
-SCALAR_DTYPES = ("int", "float", "str", "bool")
-_SERDE_CHAR = {"int": "i", "float": "f", "str": "s", "bool": "b"}
+_NAMED_DTYPES = ("int", "float", "str", "bool", "date")
+SCALAR_DTYPES = _NAMED_DTYPES + ("decimal(p,s)",)
+_SERDE_CHAR = {"int": "i", "float": "f", "str": "s", "bool": "b",
+               "date": "D"}
+_DECIMAL = re.compile(r"^decimal\(\s*(\d+)\s*,\s*(\d+)\s*\)$")
+#: Spark's widest decimal, and the precision it gives a 64-bit int
+MAX_PRECISION = 38
+_INT_PRECISION = 20
+#: the ordinal of day 0 of the engine's dates
+EPOCH_ORDINAL = datetime.date(1970, 1, 1).toordinal()
+#: decimal literals fold exactly: no result of these needs more digits
+_EXACT = decimal.Context(prec=4 * MAX_PRECISION)
+
+
+def decimal_type(precision: int, scale: int) -> str:
+    return f"decimal({precision},{scale})"
+
+
+def _decimal_ps(dtype: str) -> tuple | None:
+    m = _DECIMAL.match(dtype)
+    return (int(m.group(1)), int(m.group(2))) if m else None
+
+
+def decimal_scale(dtype: str) -> int | None:
+    """The scale of a "decimal(p,s)" dtype; None for any other dtype."""
+    ps = _decimal_ps(dtype)
+    return ps[1] if ps else None
+
+
+def exact_scale(dtype: str) -> int | None:
+    """Digits after the point of an exact number: 0 for "int", s for a
+    decimal, None for every other dtype."""
+    return 0 if dtype == "int" else decimal_scale(dtype)
+
+
+def int_backed(dtype: str) -> bool:
+    """Held as an int64 column by the vectorized path."""
+    return dtype in ("int", "date") or decimal_scale(dtype) is not None
+
+
+def canonical_dtype(dtype: str) -> str:
+    """``dtype`` in its one spelling ("decimal(15,2)"); raises ValueError
+    for an unknown dtype."""
+    dtype = str(dtype)
+    if dtype.startswith("list:") and not dtype.startswith("list:list:"):
+        return "list:" + canonical_dtype(dtype[5:])
+    ps = _decimal_ps(dtype)
+    if ps is not None:
+        p, s = ps
+        if not 1 <= p <= MAX_PRECISION or s > p:
+            raise ValueError(f"bad decimal dtype {dtype!r}: need "
+                             f"1 <= p <= {MAX_PRECISION} and s <= p")
+        return decimal_type(p, s)
+    if dtype not in _NAMED_DTYPES:
+        raise ValueError(f"unknown dtype {dtype!r} (have {SCALAR_DTYPES})")
+    return dtype
 
 
 def dtype_serde_char(dtype: str) -> str:
-    """Map a DataFrame dtype to the serde column-schema grammar."""
+    """Map a DataFrame dtype to the serde column-schema grammar: a
+    decimal carries its scale ("d2"), a date its own code ("D")."""
     if dtype.startswith("list:"):
         return "l(%s)" % dtype_serde_char(dtype[5:])
+    scale = decimal_scale(dtype)
+    if scale is not None:
+        return "d%d" % scale
     return _SERDE_CHAR[dtype]
+
+
+# ------------------------------------------------- dates and decimals
+
+
+def div_half_up(a: int, b: int) -> int:
+    """a / b rounded to the nearest int, halves away from zero."""
+    q, r = divmod(abs(a), abs(b))
+    if 2 * r >= abs(b):
+        q += 1
+    return -q if (a < 0) != (b < 0) else q
+
+
+def unscaled(d: decimal.Decimal, scale: int) -> int:
+    """``d`` as an int counted in units of 10**-scale, rounded half up."""
+    sign, digits, exp = d.as_tuple()
+    if not isinstance(exp, int):
+        raise ValueError(f"not a finite decimal: {d}")
+    coeff = int("".join(map(str, digits)) or "0")
+    shift = exp + scale
+    v = coeff * 10**shift if shift >= 0 else div_half_up(coeff,
+                                                         10**-shift)
+    return -v if sign else v
+
+
+def parse_decimal(text: str, scale: int) -> int:
+    """The unscaled int of decimal text at ``scale``, with no float in
+    between ("21168.23" -> 2116823, "17" -> 1700 at scale 2); extra
+    digits round half up."""
+    try:
+        return unscaled(decimal.Decimal(text), scale)
+    except decimal.InvalidOperation:
+        raise ValueError(f"invalid decimal {text!r}") from None
+
+
+def parse_date(text: str) -> int:
+    """Days since 1970-01-01 of an ISO date ("1998-12-01")."""
+    return datetime.date.fromisoformat(text).toordinal() - EPOCH_ORDINAL
+
+
+def shift_date(days: int, months: int, ndays: int) -> int:
+    """``days`` moved by whole months (clamped to the month's last day,
+    as Spark's add_months) and then by ``ndays``."""
+    if months:
+        d = datetime.date.fromordinal(days + EPOCH_ORDINAL)
+        y, m = divmod(d.year * 12 + d.month - 1 + months, 12)
+        day = min(d.day, calendar.monthrange(y, m + 1)[1])
+        days = datetime.date(y, m + 1, day).toordinal() - EPOCH_ORDINAL
+    return days + ndays
+
+
+def _decimal_scan(scale: int):
+    def cast(text):
+        return parse_decimal(text, scale)
+    return cast
+
+
+def scan_cast(dtype: str) -> Callable:
+    """The CSV field parser of ``dtype``: a date to its day number, a
+    decimal to its unscaled int."""
+    scale = decimal_scale(dtype)
+    if scale is not None:
+        return _decimal_scan(scale)
+    if dtype == "date":
+        return parse_date
+    return CASTS[dtype]
+
+
+def _surface_decimal(scale: int):
+    def surface(v):
+        return None if v is None else decimal.Decimal(f"{v}E-{scale}")
+    return surface
+
+
+def _surface_date(v):
+    return (None if v is None
+            else datetime.date.fromordinal(v + EPOCH_ORDINAL))
+
+
+def _surface_value(dtype: str):
+    if dtype.startswith("list:"):
+        inner = _surface_value(dtype[5:])
+        if inner is None:
+            return None
+        return lambda v: None if v is None else [inner(x) for x in v]
+    scale = decimal_scale(dtype)
+    if scale is not None:
+        return _surface_decimal(scale)
+    return _surface_date if dtype == "date" else None
+
+
+def surface_fn(schema: "Schema") -> Callable | None:
+    """Row -> row turning the engine's day numbers and unscaled ints into
+    ``datetime.date`` and ``decimal.Decimal``; None where the schema has
+    neither."""
+    convs = [(i, f) for i, (_, t) in enumerate(schema)
+             if (f := _surface_value(t)) is not None]
+    if not convs:
+        return None
+
+    def surface(row):
+        out = list(row)
+        for i, f in convs:
+            out[i] = f(out[i])
+        return tuple(out)
+    return surface
+
+
+def internal_value(dtype: str, v):
+    """A user's value of a date or decimal column (``datetime.date``,
+    ``decimal.Decimal`` or a whole int) as the engine holds it."""
+    if v is None:
+        return None
+    scale = decimal_scale(dtype)
+    if scale is not None and type(v) is int:
+        return v * 10**scale
+    if scale is not None and type(v) is decimal.Decimal:
+        return unscaled(v, scale)
+    if dtype == "date" and type(v) is datetime.date:
+        return v.toordinal() - EPOCH_ORDINAL
+    raise TypeError(f"cannot hold {v!r} in a {dtype} column")
 
 
 def _parse_bool(s: str) -> bool:
@@ -66,18 +262,19 @@ class Schema:
     __slots__ = ("fields",)
 
     def __init__(self, fields: Iterable):
-        fields = tuple((str(n), str(t)) for n, t in fields)
+        out = []
         seen = set()
         for name, dtype in fields:
+            name = str(name)
             if name in seen:
                 raise ValueError(f"duplicate column name {name!r} "
                                  f"(alias aggregate/select outputs)")
             seen.add(name)
-            base = dtype[5:] if dtype.startswith("list:") else dtype
-            if base not in SCALAR_DTYPES:
-                raise ValueError(f"unknown dtype {dtype!r} for column "
-                                 f"{name!r} (have {SCALAR_DTYPES})")
-        self.fields = fields
+            try:
+                out.append((name, canonical_dtype(dtype)))
+            except ValueError as e:
+                raise ValueError(f"{e} for column {name!r}") from None
+        self.fields = tuple(out)
 
     @property
     def names(self) -> tuple:
@@ -166,8 +363,8 @@ class Expr:
         raise NotImplementedError
 
     # ------------------------------------------------- operator building
-    def _bin(self, op: str, other) -> "BinOp":
-        return BinOp(op, self, _as_expr(other))
+    def _bin(self, op: str, other) -> "Expr":
+        return fold(BinOp(op, self, _as_expr(other)))
 
     def __add__(self, other):
         return self._bin("+", other)
@@ -177,6 +374,15 @@ class Expr:
 
     def __mul__(self, other):
         return self._bin("*", other)
+
+    def __radd__(self, other):
+        return fold(BinOp("+", _as_expr(other), self))
+
+    def __rsub__(self, other):
+        return fold(BinOp("-", _as_expr(other), self))
+
+    def __rmul__(self, other):
+        return fold(BinOp("*", _as_expr(other), self))
 
     def __truediv__(self, other):
         return self._bin("/", other)
@@ -223,6 +429,10 @@ class Expr:
     def cast(self, dtype: str) -> "Cast":
         return Cast(self, dtype)
 
+    def between(self, lo, hi) -> "Expr":
+        """SQL BETWEEN: ``lo <= self <= hi``, both ends inclusive."""
+        return (self >= lo) & (self <= hi)
+
     def __repr__(self):
         return f"<expr {self.sql()}>"
 
@@ -255,24 +465,78 @@ _LIT_DTYPE = {bool: "bool", int: "int", float: "float", str: "str"}
 
 
 class Lit(Expr):
+    """A constant. ``value`` is as the user gave it; ``internal`` is how
+    the engine holds it (a date's day number, a decimal's unscaled
+    int at the literal's own scale)."""
+
     def __init__(self, value):
-        if type(value) not in _LIT_DTYPE:
+        t = type(value)
+        if t is decimal.Decimal:
+            if not value.is_finite():
+                raise TypeError(f"unsupported literal {value!r}")
+            scale = max(0, -value.as_tuple().exponent)
+            self.internal = unscaled(value, scale)
+            digits = len(str(abs(self.internal)))
+            if max(digits, scale) > MAX_PRECISION:
+                raise TypeError(f"decimal literal {value} has more than "
+                                f"{MAX_PRECISION} digits")
+            self._dtype = decimal_type(max(digits, scale, 1), scale)
+        elif t is datetime.date:
+            self.internal = value.toordinal() - EPOCH_ORDINAL
+            self._dtype = "date"
+        elif t in _LIT_DTYPE:
+            self.internal = value
+            self._dtype = _LIT_DTYPE[t]
+        else:
             raise TypeError(f"unsupported literal {value!r} "
-                            f"(int/float/str/bool)")
+                            f"(int/float/str/bool/date/Decimal)")
         self.value = value
 
     def dtype(self, schema):
-        return _LIT_DTYPE[type(self.value)]
+        return self._dtype
 
     def bind(self, schema):
-        v = self.value
+        v = self.internal
         return lambda row: v
 
     def substitute(self, mapping):
         return self
 
     def sql(self):
+        if self._dtype == "date":
+            return f"date '{self.value.isoformat()}'"
+        if self._dtype.startswith("decimal"):
+            return str(self.value)
         return repr(self.value)
+
+
+INTERVAL_UNITS = ("day", "month", "year")
+
+
+class Interval(Expr):
+    """``interval 'n' unit``: added to or subtracted from a date only."""
+
+    def __init__(self, n: int, unit: str):
+        if type(n) is not int or unit not in INTERVAL_UNITS:
+            raise ValueError(f"interval needs an int count and a unit of "
+                             f"{INTERVAL_UNITS}, got {n!r} {unit!r}")
+        self.n = n
+        self.unit = unit
+        self.months = n * 12 if unit == "year" else (
+            n if unit == "month" else 0)
+        self.days = n if unit == "day" else 0
+
+    def dtype(self, schema):
+        return "interval"
+
+    def bind(self, schema):
+        raise TypeError(f"{self.sql()}: an interval only shifts a date")
+
+    def substitute(self, mapping):
+        return self
+
+    def sql(self):
+        return f"interval '{self.n}' {self.unit}"
 
 
 def _div(a, b):
@@ -303,6 +567,17 @@ class BinOp(Expr):
 
     def dtype(self, schema):
         lt, rt = self.left.dtype(schema), self.right.dtype(schema)
+        if "interval" in (lt, rt):
+            if self.interval_sign(lt, rt) is None:
+                raise TypeError(f"{self.sql()}: an interval is only added "
+                                f"to or subtracted from a date")
+            return "date"
+        if self.op in _ARITH and lt == rt == "date":
+            if self.op != "-":
+                raise TypeError(f"{self.sql()}: dates only subtract")
+            return "int"  # days between
+        if (decimal_scale(lt) is not None or decimal_scale(rt) is not None):
+            return _decimal_result(self, lt, rt)
         if self.op in _ARITH:
             if lt not in _NUMERIC or rt not in _NUMERIC:
                 if self.op == "+" and lt == rt == "str":
@@ -327,7 +602,31 @@ class BinOp(Expr):
                             f"{rt}")
         return "bool"
 
+    def interval_sign(self, lt: str, rt: str) -> int | None:
+        """+1 / -1 for date + / - interval (or interval + date), else
+        None."""
+        if lt == "date" and rt == "interval" and self.op in ("+", "-"):
+            return 1 if self.op == "+" else -1
+        if lt == "interval" and rt == "date" and self.op == "+":
+            return 1
+        return None
+
+    def scales(self, schema) -> tuple:
+        """(left multiplier, right multiplier) that bring two exact
+        numbers to one scale for ``+``/``-`` and comparisons; (1, 1)
+        where nothing is aligned."""
+        ls = exact_scale(self.left.dtype(schema))
+        rs = exact_scale(self.right.dtype(schema))
+        if ls is None or rs is None or self.op in ("*", "/", "%",
+                                                   "and", "or"):
+            return 1, 1
+        s = max(ls, rs)
+        return 10**(s - ls), 10**(s - rs)
+
     def bind(self, schema):
+        lt, rt = self.left.dtype(schema), self.right.dtype(schema)
+        if "interval" in (lt, rt):
+            return self._bind_interval(schema, lt, rt)
         lf, rf = self.left.bind(schema), self.right.bind(schema)
         if self.op == "and":
             # SHORT-CIRCUIT, not operator.and_: the optimizer merges
@@ -356,6 +655,13 @@ class BinOp(Expr):
                 return None if a is None or b is None else False
             return or_
         fn = _OPS[self.op]
+        ma, mb = self.scales(schema)
+        if self.op == "/" and not (decimal_scale(lt) is None
+                                   and decimal_scale(rt) is None):
+            # exact quotient of the two scaled ints, correctly rounded
+            fn = _decimal_div(10**(exact_scale(rt)), 10**(exact_scale(lt)))
+        elif ma != 1 or mb != 1:
+            fn = _aligned(fn, ma, mb)
 
         def apply(row):
             a = lf(row)
@@ -365,12 +671,87 @@ class BinOp(Expr):
             return None if b is None else fn(a, b)
         return apply
 
+    def _bind_interval(self, schema, lt, rt):
+        sign = self.interval_sign(lt, rt)
+        date_side, iv = ((self.left, self.right) if lt == "date"
+                         else (self.right, self.left))
+        if not isinstance(iv, Interval):
+            raise TypeError(f"{self.sql()}: the interval must be literal")
+        f = date_side.bind(schema)
+        months, days = sign * iv.months, sign * iv.days
+
+        def shift(row):
+            v = f(row)
+            return None if v is None else shift_date(v, months, days)
+        return shift
+
     def substitute(self, mapping):
         return BinOp(self.op, self.left.substitute(mapping),
                      self.right.substitute(mapping))
 
     def sql(self):
         return f"({self.left.sql()} {self.op} {self.right.sql()})"
+
+
+def _aligned(fn, ma: int, mb: int):
+    def aligned(a, b):
+        return fn(a * ma, b * mb)
+    return aligned
+
+
+def _decimal_div(ma: int, mb: int):
+    def div(a, b):
+        return (a * ma) / (b * mb)
+    return div
+
+
+def _decimal_result(node: BinOp, lt: str, rt: str) -> str:
+    """The dtype of a BinOp with a decimal operand."""
+    ls, rs = exact_scale(lt), exact_scale(rt)
+    if ls is None or rs is None:
+        raise TypeError(f"{node.sql()}: a decimal meets only ints and "
+                        f"decimals, got {lt}/{rt} (cast the other side)")
+    op = node.op
+    if op in ("and", "or") or op == "%":
+        raise TypeError(f"{node.sql()}: {op} does not take decimals")
+    if op == "/":
+        return "float"
+    if op not in _ARITH:
+        return "bool"
+    lp = _INT_PRECISION if lt == "int" else _decimal_ps(lt)[0]
+    rp = _INT_PRECISION if rt == "int" else _decimal_ps(rt)[0]
+    if op == "*":
+        scale, precision = ls + rs, lp + rp + 1
+    else:
+        scale = max(ls, rs)
+        precision = max(lp - ls, rp - rs) + scale + 1
+    if scale > MAX_PRECISION:
+        raise TypeError(f"{node.sql()}: scale {scale} is past "
+                        f"{MAX_PRECISION}")
+    return decimal_type(min(precision, MAX_PRECISION), scale)
+
+
+def fold(e: Expr) -> Expr:
+    """Fold a BinOp of literal dates, intervals and decimals into one
+    literal (``date '1998-12-01' - interval '90' day`` is ``date
+    '1998-09-02'``); any other expression comes back as it is."""
+    if not isinstance(e, BinOp) or e.op not in ("+", "-", "*"):
+        return e
+    a, b = e.left, e.right
+    if {type(a), type(b)} == {Lit, Interval}:
+        sign = e.interval_sign(a.dtype(None), b.dtype(None))
+        if sign is None:
+            return e
+        day, iv = (a, b) if isinstance(b, Interval) else (b, a)
+        days = shift_date(day.internal, sign * iv.months, sign * iv.days)
+        return Lit(datetime.date.fromordinal(days + EPOCH_ORDINAL))
+    if not (isinstance(a, Lit) and isinstance(b, Lit)):
+        return e
+    types_ = {type(a.value), type(b.value)}
+    if decimal.Decimal not in types_ or not types_ <= {decimal.Decimal, int}:
+        return e
+    fn = {"+": _EXACT.add, "-": _EXACT.subtract, "*": _EXACT.multiply}
+    return Lit(fn[e.op](decimal.Decimal(a.value), decimal.Decimal(b.value)))
 
 
 class Not(Expr):
@@ -438,9 +819,98 @@ class Substr(Expr):
         return f"substr({self.child.sql()}, {self.start}, {self.length})"
 
 
+def cast_fn(src: str, to: str) -> Callable:
+    """cast() on one live value of dtype ``src`` (as the engine holds
+    it) to dtype ``to``; raises TypeError for a pair with no cast. Into
+    a decimal, extra digits round half up; a decimal to an int
+    truncates toward zero, as Spark."""
+    if src == to:
+        return _identity
+    to_scale, src_scale = decimal_scale(to), decimal_scale(src)
+    if to_scale is not None:
+        if src == "int":
+            return _times(10**to_scale)
+        if src_scale is not None:
+            if to_scale >= src_scale:
+                return _times(10**(to_scale - src_scale))
+            return _rescale_down(10**(src_scale - to_scale))
+        if src == "str":
+            return _decimal_scan(to_scale)
+        if src == "float":
+            return _float_to_decimal(to_scale)
+    elif to == "date":
+        if src == "str":
+            return parse_date
+    elif src_scale is not None:
+        if to == "float":
+            return _over(10**src_scale)
+        if to == "int":
+            return _truncate(10**src_scale)
+        if to == "str":
+            return _decimal_text(src_scale)
+        if to == "bool":
+            return bool
+    elif src == "date":
+        if to == "str":
+            return _date_text
+    elif not src.startswith("list:"):
+        return _RUNTIME_CASTS[to]
+    raise TypeError(f"no cast from {src} to {to}")
+
+
+def _identity(v):
+    return v
+
+
+def _times(m: int):
+    def times(v):
+        return v * m
+    return times
+
+
+def _over(m: int):
+    def over(v):
+        return v / m  # the exact quotient, correctly rounded
+    return over
+
+
+def _rescale_down(m: int):
+    def rescale(v):
+        return div_half_up(v, m)
+    return rescale
+
+
+def _truncate(m: int):
+    def truncate(v):
+        return -(-v // m) if v < 0 else v // m
+    return truncate
+
+
+def _float_to_decimal(scale: int):
+    def cast(v):
+        return unscaled(decimal.Decimal(v), scale)
+    return cast
+
+
+def _decimal_text(scale: int):
+    surface = _surface_decimal(scale)
+
+    def text(v):
+        return str(surface(v))
+    return text
+
+
+def _date_text(v):
+    return _surface_date(v).isoformat()
+
+
 class Cast(Expr):
     def __init__(self, child: Expr, to: str):
-        if to not in SCALAR_DTYPES:
+        try:
+            to = canonical_dtype(to)
+        except ValueError:
+            raise ValueError(f"cannot cast to {to!r}") from None
+        if to.startswith("list:"):
             raise ValueError(f"cannot cast to {to!r}")
         self.child = child
         self.to = to
@@ -449,12 +919,12 @@ class Cast(Expr):
         return [self.child]
 
     def dtype(self, schema):
-        self.child.dtype(schema)  # validate the subtree
+        cast_fn(self.child.dtype(schema), self.to)  # validate the pair
         return self.to
 
     def bind(self, schema):
         f = self.child.bind(schema)
-        caster = _RUNTIME_CASTS[self.to]
+        caster = cast_fn(self.child.dtype(schema), self.to)
 
         def cast(row):
             v = f(row)
@@ -562,18 +1032,40 @@ class AggExpr:
         ct = self.child.dtype(schema) if self.child is not None else None
         if self.op == "count":
             return "int"
+        ps = _decimal_ps(ct) if ct is not None else None
+        if self.op in ("sum", "avg") and ct not in _NUMERIC and ps is None:
+            raise TypeError(f"{self.sql()}: {self.op} needs a numeric arg")
         if self.op == "avg":
-            if ct not in _NUMERIC:
-                raise TypeError(f"{self.sql()}: avg needs a numeric arg")
-            return "float"
-        if self.op == "sum" and ct not in _NUMERIC:
-            raise TypeError(f"{self.sql()}: sum needs a numeric arg")
+            if ps is None:
+                return "float"
+            # Spark: decimal(p + 4, s + 4), rounded half up
+            return decimal_type(min(ps[0] + 4, MAX_PRECISION),
+                                min(ps[1] + 4, MAX_PRECISION))
+        if self.op == "sum" and ps is not None:
+            return decimal_type(min(ps[0] + 10, MAX_PRECISION), ps[1])
         if self.op == "collect_list":
             return f"list:{ct}"
         return ct  # sum/min/max keep the argument dtype
 
     def alias(self, name: str) -> "AggExpr":
         return AggExpr(self.op, self.child, name=name)
+
+    def empty_value(self):
+        """The aggregate over no rows (a global aggregate of an empty
+        input): 0 for count, [] for collect_list, NULL otherwise."""
+        return {"count": 0, "collect_list": []}.get(self.op)
+
+    def avg_fn(self, schema: Schema) -> Callable:
+        """(sum, count) -> avg: a float, or for a decimal the unscaled
+        quotient at the result's scale, rounded half up."""
+        scale = decimal_scale(self.child.dtype(schema))
+        if scale is None:
+            return operator.truediv
+        m = 10**(decimal_scale(self.dtype(schema)) - scale)
+
+        def avg(total, count):
+            return div_half_up(total * m, count)
+        return avg
 
     def substitute(self, mapping) -> "AggExpr":
         child = (self.child.substitute(mapping)
@@ -597,6 +1089,12 @@ def col(name: str) -> Col:
 
 def lit(value) -> Lit:
     return Lit(value)
+
+
+def interval(n: int, unit: str) -> Interval:
+    """``interval 'n' day|month|year``, to add to or subtract from a
+    date: ``lit(date(1998, 12, 1)) - interval(90, "day")``."""
+    return Interval(n, unit)
 
 
 def udf(fn: Callable, dtype: str, *, name: str | None = None,

@@ -50,7 +50,8 @@ import operator
 from repro.core import rdd as R
 from repro.sql import plan as P
 from repro.sql import vectorized as V
-from repro.sql.expr import CASTS, Schema, dtype_serde_char
+from repro.sql.expr import (Schema, decimal_scale, dtype_serde_char,
+                             internal_value, scan_cast)
 
 _SLOT_MERGE = {"sum": operator.add, "min": min, "max": max}
 
@@ -233,7 +234,7 @@ def _lower_engine(node: P.Plan, ctx) -> R.RDD:
     if isinstance(node, P.Scan):
         return _lower_scan(node, ctx)
     if isinstance(node, P.RddScan):
-        return node.rdd
+        return _internalized(node.rdd, node.rdd_schema)
     if isinstance(node, P.Project):
         base = node.child.schema()
         fns = [e.bind(base) for _, e in node.cols]
@@ -278,8 +279,24 @@ def _lower_scan(node: P.Scan, ctx) -> R.RDD:
     full = node.full_schema
     sel = node.schema().names
     idx = [full.index(n) for n in sel]
-    casters = [CASTS[full.dtype_of(n)] for n in sel]
+    casters = [scan_cast(full.dtype_of(n)) for n in sel]
     return ctx.textFile(node.key, node.nparts).map(_parse_fn(idx, casters))
+
+
+def _internalized(rdd: R.RDD, schema: Schema) -> R.RDD:
+    """The user's rows with their dates and decimals as the engine holds
+    them (day numbers, unscaled ints)."""
+    convs = [(i, t) for i, (_, t) in enumerate(schema)
+             if t == "date" or decimal_scale(t) is not None]
+    if not convs:
+        return rdd
+
+    def internal(row):
+        out = list(row)
+        for i, t in convs:
+            out[i] = internal_value(t, out[i])
+        return tuple(out)
+    return rdd.map(internal)
 
 
 def _parse_fn(idx: list, casters: list):
@@ -335,7 +352,7 @@ def _vector_segment(base: P.Plan, steps: list, ctx):
         full = base.full_schema
         sel = base.schema().names
         idx = [full.index(n) for n in sel]
-        casters = [CASTS[full.dtype_of(n)] for n in sel]
+        casters = [scan_cast(full.dtype_of(n)) for n in sel]
         ingest = V.scan_ingest(
             [(i, full.dtype_of(n), c)
              for i, n, c in zip(idx, sel, casters)])
@@ -484,6 +501,7 @@ def _lower_partial(node: P.Aggregate, ctx, base: Schema,
     slot_ops: list = []
     inits: list = []
     layout: list = []  # (op, first slot, slot count) per aggregate
+    avgs: dict = {}  # first slot of an avg -> its (sum, count) finish
     vchars: list = []
     for name, a in node.aggs:
         off = len(slot_ops)
@@ -498,6 +516,7 @@ def _lower_partial(node: P.Aggregate, ctx, base: Schema,
             slot_ops += ["sum", "sum"]
             inits += [arg, _one]
             vchars += [argc, "i"]
+            avgs[off] = a.avg_fn(base)
         else:  # sum / min / max
             slot_ops.append(a.op)
             inits.append(arg)
@@ -516,7 +535,7 @@ def _lower_partial(node: P.Aggregate, ctx, base: Schema,
         out = []
         for op, off, _width in layout:
             if op == "avg":
-                out.append(vals[off] / vals[off + 1])
+                out.append(avgs[off](vals[off], vals[off + 1]))
             else:
                 out.append(vals[off])
         return key + tuple(out)
@@ -541,9 +560,36 @@ def _lower_partial(node: P.Aggregate, ctx, base: Schema,
         mapped = _lower_engine(node.child, ctx).map(mapper)
     vschema = "t(%s)" % ",".join(vchars) if vchars else None
     agged = mapped.reduceByKey(
-        merge, node.nparts or mapped.nparts, transport=node.transport,
+        merge, _agg_parts(node, mapped), transport=node.transport,
         batch_schema=(kschema, vschema) if kschema else None)
-    return agged.map(finalize)
+    return _global_default(node, agged.map(finalize))
+
+
+def _agg_parts(node: P.Aggregate, mapped: R.RDD) -> int:
+    """Reduce partitions: a global aggregate (no keys) has one group, so
+    one partition, which then always holds its one row."""
+    if not node.keys:
+        return 1
+    return node.nparts or mapped.nparts
+
+
+def _global_default(node: P.Aggregate, rdd: R.RDD) -> R.RDD:
+    """A global aggregate answers one row even over no input rows."""
+    if node.keys:
+        return rdd
+    return rdd.mapPartitions(_or_row(
+        tuple(a.empty_value() for _, a in node.aggs)))
+
+
+def _or_row(default: tuple):
+    def or_row(it):
+        empty = True
+        for row in it:
+            empty = False
+            yield row
+        if empty:
+            yield default
+    return or_row
 
 
 def _lower_full(node: P.Aggregate, ctx, base: Schema,
@@ -555,7 +601,8 @@ def _lower_full(node: P.Aggregate, ctx, base: Schema,
     aggfns = []
     for name, a in node.aggs:
         arg = a.child.bind(base) if a.child is not None else None
-        aggfns.append(_group_agg_fn(a.op, arg))
+        avg = a.avg_fn(base) if a.op == "avg" else None
+        aggfns.append(_group_agg_fn(a.op, arg, avg))
 
     def mapper(row):
         return (keyer(row), row)
@@ -578,9 +625,9 @@ def _lower_full(node: P.Aggregate, ctx, base: Schema,
     if mapped is None:
         mapped = _lower_engine(node.child, ctx).map(mapper)
     grouped = mapped.groupByKey(
-        node.nparts or mapped.nparts, transport=node.transport,
+        _agg_parts(node, mapped), transport=node.transport,
         batch_schema=(kschema, vschema) if kschema else None)
-    return grouped.map(finalize)
+    return _global_default(node, grouped.map(finalize))
 
 
 # ---------------------------------------------------------- explain marks
@@ -627,13 +674,13 @@ def vector_markers(plan: P.Plan, config) -> dict:
     return marks
 
 
-def _group_agg_fn(op: str, arg):
+def _group_agg_fn(op: str, arg, avg=None):
     if op == "count":
         return len
     if op == "sum":
         return lambda rows: sum(arg(r) for r in rows)
     if op == "avg":
-        return lambda rows: sum(arg(r) for r in rows) / len(rows)
+        return lambda rows: avg(sum(arg(r) for r in rows), len(rows))
     if op == "min":
         return lambda rows: min(arg(r) for r in rows)
     if op == "max":

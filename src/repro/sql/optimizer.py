@@ -34,7 +34,8 @@ from __future__ import annotations
 
 from repro.core import costs
 from repro.core.dag import estimate_lineage_bytes
-from repro.sql.expr import (Col, Lit, join_conjuncts, split_conjuncts)
+from repro.sql.expr import (Col, Lit, decimal_scale, join_conjuncts,
+                            split_conjuncts)
 from repro.sql.plan import (Aggregate, Cached, Filter, Join, Limit, Plan,
                             Project, RddScan, Scan, Sort, Window,
                             explain_str)
@@ -42,7 +43,7 @@ from repro.sql.plan import (Aggregate, Cached, Filter, Join, Limit, Plan,
 #: map-side combine ships partially-merged values; assume it halves bytes
 PARTIAL_COMBINE_FACTOR = 0.5
 #: rough per-value wire widths for the projection-ratio estimate
-_DTYPE_WIDTH = {"int": 8, "float": 8, "bool": 1, "str": 16}
+_DTYPE_WIDTH = {"int": 8, "float": 8, "bool": 1, "str": 16, "date": 4}
 
 
 def optimize(plan: Plan, ctx=None) -> Plan:
@@ -188,7 +189,9 @@ def _push_filter_aggregate(node: Filter, agg: Aggregate) -> Plan:
     aggregate output stays above."""
     key_names = {n for n, _ in agg.keys}
     mapping = {n: e for n, e in agg.keys}
-    if not all(e.deterministic for e in mapping.values()):
+    if not agg.keys or not all(e.deterministic for e in mapping.values()):
+        # a global aggregate answers a row even for no input: a filter
+        # moved below it would change that row, not drop it
         return node
     pushed, kept = [], []
     for conj in split_conjuncts(node.pred):
@@ -220,7 +223,8 @@ def _narrow(child: Plan, needed: set) -> Plan:
     names = child.schema().names
     if set(names) <= needed:
         return child
-    keep = [n for n in names if n in needed]
+    # rows keep one column, so that a global count still sees each row
+    keep = [n for n in names if n in needed] or [names[0]]
     return Project(child, [(n, Col(n)) for n in keep])
 
 
@@ -311,7 +315,8 @@ def _choose_partial(node: Plan) -> Plan:
 
 
 def _row_width(schema) -> float:
-    return sum(_DTYPE_WIDTH.get(t, 32) for _, t in schema) or 1.0
+    return sum(_DTYPE_WIDTH.get(t, 32 if decimal_scale(t) is None else 8)
+               for _, t in schema) or 1.0
 
 
 def _choose_transport(node: Plan, ctx) -> tuple:

@@ -7,8 +7,11 @@ array kernels over whole column batches (docs/vectorized_execution.md).
 column is one of three shapes, fixed by dtype:
 
   * "int" / "float" / "bool"  -> a numpy int64 / float64 / bool array
+  * "date" / "decimal(p,s)"   -> a numpy int64 array of day numbers /
+                                 unscaled ints (repro.sql.expr)
   * "str" and "list:..."      -> a plain Python list
-  * a literal                 -> a bare Python scalar (broadcasts)
+  * a literal                 -> a bare Python scalar (broadcasts), a
+                                 date or decimal as the engine holds it
 
 The contract with the row path is BIT-IDENTICAL RESULTS. Wherever a
 numpy shortcut could diverge from the Python semantics of the bound row
@@ -17,8 +20,11 @@ closures, the compiled code either takes an exact path or raises
 original row closures:
 
   * int64 arithmetic wraps silently in numpy (and ``np.errstate`` does
-    NOT trap it) — every int +/-/* is shadowed in float64 and any result
-    magnitude past 2**62 falls back (Python ints are unbounded);
+    NOT trap it) — every int, date and decimal +/-/*, and every scale
+    alignment of a decimal, is shadowed in float64 and any magnitude
+    past 2**62 falls back (Python ints are unbounded); a decimal
+    quotient is taken in float64 only while both scaled operands are
+    exact there (below 2**53);
   * division/modulo by zero raises in Python but yields inf/nan/0 in
     numpy — numeric stages run under ``errstate(divide="raise",
     invalid="raise")`` and the FloatingPointError falls back, which also
@@ -34,7 +40,9 @@ original row closures:
 
 In fact the fused operator treats any exception from a vectorized chunk
 as a fallback signal and re-runs the chunk through the row closures, so
-a divergence can only ever cost speed, never correctness. The one
+a divergence can only ever cost speed, never correctness; the running
+task counts the chunks it ran (``fused_chunks``) and those it re-ran
+(``fused_row_fallback_chunks``). The one
 exception is ``DeviceBackendError``: a failure of the jax backend
 (import, compile or run) surfaces to the caller instead of quietly
 becoming a host answer.
@@ -50,7 +58,6 @@ from repro.core import spans
 from repro.core.shuffle import KVBatch
 from repro.sql import csvscan
 
-_NP_DTYPE = {"int": np.int64, "float": np.float64, "bool": np.bool_}
 _NUMERIC = ("int", "float")
 #: int results whose float64 shadow exceeds this may be near the int64
 #: wrap point (float error cannot bridge the 2**62..2**63 gap)
@@ -58,6 +65,9 @@ _INT_GUARD = float(2**62)
 #: ints beyond 2**53 lose precision as float64 — exact mixed comparison
 #: requires falling back to Python's exact int/float comparison
 _EXACT_F64 = float(2**53)
+#: the day numbers Python's datetime.date can hold
+_MIN_DAY = 1 - 719163
+_MAX_DAY = 3652059 - 719163
 
 
 class VectorizeUnsupported(Exception):
@@ -128,7 +138,7 @@ def compile_expr(expr, schema):
         i = schema.index(expr.name)
         return lambda cols, n: cols[i]
     if isinstance(expr, E.Lit):
-        v = expr.value
+        v = expr.internal
         return lambda cols, n: v
     if isinstance(expr, E.BinOp):
         return _compile_binop(expr, schema)
@@ -159,13 +169,68 @@ def _not(v):
     return ~np.asarray(v)
 
 
+def _times(v, m: int):
+    """``v * m`` for a scale alignment, exact or VectorFallback."""
+    if m == 1:
+        return v
+    if _is_scalar(v):
+        return v * m
+    if np.any(np.abs(v.astype(np.float64)) * float(m) > _INT_GUARD):
+        raise VectorFallback("int64 overflow risk in a decimal rescale")
+    return v * m
+
+
+def _add_months(days, months: int):
+    """``repro.sql.expr.shift_date(d, months, 0)`` of a day-number
+    array: the same month arithmetic, clamped to the month's end."""
+    if (np.any(days < _MIN_DAY) or np.any(days > _MAX_DAY)):
+        raise VectorFallback("date outside Python's range")
+    d = days.astype("datetime64[D]")
+    month = d.astype("datetime64[M]")
+    dom = (d - month.astype("datetime64[D]")).astype(np.int64)
+    target = month + months
+    start = target.astype("datetime64[D]")
+    length = ((target + 1).astype("datetime64[D]") - start).astype(np.int64)
+    out = (start + np.minimum(dom, length - 1)).astype(np.int64)
+    if np.any(out < _MIN_DAY) or np.any(out > _MAX_DAY):
+        raise VectorFallback("date outside Python's range")
+    return out
+
+
+def _compile_interval(expr, schema, lt, rt):
+    from repro.sql import expr as E
+
+    date_side, iv = ((expr.left, expr.right) if lt == "date"
+                     else (expr.right, expr.left))
+    if not isinstance(iv, E.Interval):
+        raise VectorizeUnsupported("interval")
+    sign = expr.interval_sign(lt, rt)
+    months, days = sign * iv.months, sign * iv.days
+    f = compile_expr(date_side, schema)
+
+    def f_shift(cols, n):
+        v = f(cols, n)
+        if _is_scalar(v):
+            return E.shift_date(v, months, days)
+        if months:
+            v = _add_months(v, months)
+        return v + days if days else v
+    return f_shift
+
+
 def _compile_binop(expr, schema):
     from repro.sql import expr as E
 
     lt, rt = expr.left.dtype(schema), expr.right.dtype(schema)
+    if "interval" in (lt, rt):
+        return _compile_interval(expr, schema, lt, rt)
     lf = compile_expr(expr.left, schema)
     rf = compile_expr(expr.right, schema)
     op = expr.op
+    # int, date and decimal operands are int64 columns; + - and the
+    # comparisons first bring two decimals to one scale
+    exact = E.int_backed(lt) and E.int_backed(rt)
+    ma, mb = expr.scales(schema)
 
     if op in ("and", "or"):
         # both operands evaluate EAGERLY here; the row path short-circuits.
@@ -189,7 +254,10 @@ def _compile_binop(expr, schema):
         return f_concat
 
     if op in ("+", "-", "*", "/", "%"):
-        both_int = lt == rt == "int" and op != "/"
+        both_int = exact and op != "/"
+        decimal_div = op == "/" and exact and (lt != "int" or rt != "int")
+        if decimal_div:
+            ma, mb = 10**E.exact_scale(rt), 10**E.exact_scale(lt)
         npop = {"+": np.add, "-": np.subtract, "*": np.multiply,
                 "/": np.divide, "%": np.mod}[op]
         pyop = {"+": lambda a, b: a + b, "-": lambda a, b: a - b,
@@ -199,7 +267,16 @@ def _compile_binop(expr, schema):
         def f_arith(cols, n):
             a, b = lf(cols, n), rf(cols, n)
             if _is_scalar(a) and _is_scalar(b):
-                return pyop(a, b)  # exact Python semantics
+                return pyop(a * ma, b * mb)  # exact Python semantics
+            if both_int or decimal_div:
+                a, b = _times(a, ma), _times(b, mb)
+            if decimal_div:
+                # float64 division is correctly rounded, as Python's
+                # int / int, while both operands are exact doubles
+                if (np.any(np.abs(_as_float(a)) > _EXACT_F64)
+                        or np.any(np.abs(_as_float(b)) > _EXACT_F64)):
+                    raise VectorFallback("decimal quotient past 2**53")
+                return npop(_as_float(a), _as_float(b))
             if both_int:
                 r = npop(a, b)  # int64 — may have wrapped silently
                 if op in ("+", "-", "*"):
@@ -235,6 +312,15 @@ def _compile_binop(expr, schema):
                     raise VectorFallback("int past 2**53 in float compare")
             return cmp_np(a, b)
         return f_numcmp
+    if exact:  # dates, decimals at one scale
+        cmp_np, cmp_py = npop[expr.op], pyop[expr.op]
+
+        def f_exactcmp(cols, n):
+            a, b = lf(cols, n), rf(cols, n)
+            if _is_scalar(a) and _is_scalar(b):
+                return cmp_py(a * ma, b * mb)
+            return cmp_np(_times(a, ma), _times(b, mb))
+        return f_exactcmp
     if lt == rt == "bool":
         cmp_np, cmp_py = npop[expr.op], pyop[expr.op]
 
@@ -265,12 +351,17 @@ def _compile_binop(expr, schema):
     raise VectorizeUnsupported(f"compare {lt}/{rt}")
 
 
+_BASIC = ("int", "float", "str", "bool")
+
+
 def _compile_cast(expr, schema):
     f = compile_expr(expr.child, schema)
     src = expr.child.dtype(schema)
     to = expr.to
     if src.startswith("list:"):
         raise VectorizeUnsupported("cast from list")
+    if src not in _BASIC or to not in _BASIC:
+        return _compile_exact_cast(f, src, to)
 
     def g(cols, n):
         v = f(cols, n)
@@ -310,6 +401,30 @@ def _compile_cast(expr, schema):
     return g
 
 
+def _compile_exact_cast(f, src, to):
+    """A cast to or from a date or decimal: the row path's cast on each
+    value (``repro.sql.expr.cast_fn``), so both paths agree by
+    construction; an int past int64 falls back."""
+    from repro.sql import expr as E
+
+    caster = E.cast_fn(src, to)
+    np_type = (np.int64 if E.int_backed(to) else
+               {"float": np.float64, "bool": np.bool_}.get(to))
+
+    def g(cols, n):
+        v = f(cols, n)
+        if _is_scalar(v):
+            return caster(v)
+        if src == to:
+            return v
+        vals = to_list(v, n)
+        if any(x is None for x in vals):
+            raise VectorFallback("NULL in a cast")
+        out = [caster(x) for x in vals]
+        return out if np_type is None else np.array(out, dtype=np_type)
+    return g
+
+
 # ------------------------------------------------------------- ingestion
 
 
@@ -342,7 +457,12 @@ def _count_chunk(name: str) -> None:
 def rows_ingest(dtypes):
     """Columnize a chunk of already-materialized rows, checking exact
     concrete types (bool is not int, 1.0 is not 1 — same conformance rule
-    as the wire format). Non-conforming chunks fall back to row closures."""
+    as the wire format). Non-conforming chunks fall back to row closures.
+    Dates and decimals arrive as the engine holds them, ints."""
+    from repro.sql.expr import int_backed
+
+    dtypes = ["int" if int_backed(t) else t for t in dtypes]
+
     def ingest(rows):
         n = len(rows)
         cols = []
@@ -440,31 +560,52 @@ def make_kv_agg_emit(key_fns, slot_fns, slot_ops, backend):
 def grouped_records(key_cols, slot_cols, slot_ops, n, backend="numpy"):
     if n == 0:
         return []
-    keys = list(zip(*[to_list(c, n) for c in key_cols]))
-    index: dict = {}
-    gids = np.empty(n, dtype=np.int64)
-    first = []
-    for i, k in enumerate(keys):
-        g = index.get(k)
-        if g is None:
-            g = len(index)
-            index[k] = g
-            first.append(i)
-        gids[i] = g
-    ng = len(index)
+    if key_cols:
+        keys = list(zip(*[to_list(c, n) for c in key_cols]))
+        index: dict = {}
+        gids = np.empty(n, dtype=np.int64)
+        first = []
+        for i, k in enumerate(keys):
+            g = index.get(k)
+            if g is None:
+                g = len(index)
+                index[k] = g
+                first.append(i)
+            gids[i] = g
+        uniq = list(index)  # insertion order == first occurrence
+    else:  # a global aggregate: one group
+        gids = np.zeros(n, dtype=np.int64)
+        first, uniq = [0], [()]
+    ng = len(uniq)
     first_arr = np.array(first, dtype=np.int64)
-    out_slots = [to_list(_fold_slot(op, c, gids, ng, first_arr, n, backend),
-                         ng)
-                 for op, c in zip(slot_ops, slot_cols)]
-    uniq = list(index)  # insertion order == first occurrence
+    # a column that several slots fold alike (sum(x) beside avg(x)) is
+    # folded once
+    folded: dict = {}
+    if backend == "jax":
+        device = {id(c): c for op, c in zip(slot_ops, slot_cols)
+                  if op == "sum" and isinstance(c, np.ndarray)
+                  and c.dtype == np.int64}
+        if device:
+            sums = _jax_int_sums(list(device.values()), gids, ng)
+            if sums is not None:
+                folded.update((("sum", i), s.tolist())
+                              for i, s in zip(device, sums))
+    out_slots = []
+    for op, c in zip(slot_ops, slot_cols):
+        key = (op, id(c))
+        if key not in folded:
+            folded[key] = to_list(
+                _fold_slot(op, c, gids, ng, first_arr, n), ng)
+        out_slots.append(folded[key])
     return [(k, tuple(s[g] for s in out_slots))
             for g, k in enumerate(uniq)]
 
 
-def _fold_slot(op, col, gids, ng, first, n, backend):
-    """Fold one slot column per group, reproducing the row path's left
-    fold exactly: init from the group's FIRST value, accumulate the rest
-    in row order (np.<op>.at applies sequentially)."""
+def _fold_slot(op, col, gids, ng, first, n):
+    """Fold one slot column per group on the host, reproducing the row
+    path's left fold exactly: init from the group's FIRST value,
+    accumulate the rest in row order (np.<op>.at applies
+    sequentially)."""
     if _is_scalar(col):
         if op == "sum" and type(col) is int:
             counts = np.bincount(gids, minlength=ng)
@@ -476,10 +617,6 @@ def _fold_slot(op, col, gids, ng, first, n, backend):
         return _py_fold(op, col, gids, ng)
     if op == "sum":
         if col.dtype == np.int64:
-            if backend == "jax":
-                folded = _jax_int_sum(col, gids, ng)
-                if folded is not None:
-                    return folded
             # bound the worst-case partial: if even the sum of |v| stays
             # far from the wrap point, int64 accumulation is exact
             if float(np.abs(col).astype(np.float64).sum()) > _INT_GUARD:
@@ -523,22 +660,29 @@ def _py_fold(op, vals, gids, ng):
     return acc
 
 
-def _jax_int_sum(col, gids, ng):
-    """Route an int64 group sum through the kernels/ backend
-    (vector_backend="jax"). Integer addition is associative, so an
-    order-free segment sum is exact as long as it cannot overflow — the
-    same magnitude bound as the numpy path. Returns None only past that
-    bound, where the numpy path's bigint fold takes over; every backend
-    failure raises DeviceBackendError. Counts land in the running task's
-    stats."""
+def _jax_int_sums(cols, gids, ng):
+    """Route a chunk's int64 group sums through the kernels/ backend
+    (vector_backend="jax") as ONE grouped sum: the K columns stacked,
+    column j's rows in buckets j * ng + group. Integer addition is
+    associative, so an order-free segment sum is exact as long as it
+    cannot overflow — the same magnitude bound as the numpy path.
+    Returns a (K, ng) array, or None past that bound, where the numpy
+    path's folds take over; every backend failure raises
+    DeviceBackendError. Counts land in the running task's stats."""
     from repro.core.executors import task_stats
+    k = len(cols)
     try:
         from repro.kernels.ops import grouped_reduce
-        return grouped_reduce(col, gids, ng, stats=task_stats())
+        vals = cols[0] if k == 1 else np.concatenate(cols)
+        ids = gids if k == 1 else np.concatenate(
+            [gids + j * ng for j in range(k)])
+        out = grouped_reduce(vals, ids, k * ng, columns=k,
+                             stats=task_stats())
     except Exception as e:
         raise DeviceBackendError(
-            f"jax grouped sum over {len(col)} rows / {ng} groups failed: "
-            f"{type(e).__name__}: {e}") from e
+            f"jax grouped sum over {len(gids)} rows x {k} columns / {ng} "
+            f"groups failed: {type(e).__name__}: {e}") from e
+    return None if out is None else np.asarray(out).reshape(k, ng)
 
 
 # ---------------------------------------------------------- fused operator
@@ -552,7 +696,9 @@ def make_fused(ingest, stages, emit, row_chain, batch_rows):
     per-row closure pipeline for the same plan segment). Emissions are
     materialized per chunk BEFORE yielding so a mid-chunk fallback never
     double-emits. A chunk's pull, parse and operator work run under the
-    ``flint.scan``, ``flint.ingest`` and ``flint.fused`` spans."""
+    ``flint.scan``, ``flint.ingest`` and ``flint.fused`` spans; the
+    running task counts every chunk (``fused_chunks``) and each one
+    re-run on the row path (``fused_row_fallback_chunks``)."""
     def fused(it):
         it = iter(it)
         while True:
@@ -560,6 +706,7 @@ def make_fused(ingest, stages, emit, row_chain, batch_rows):
                 chunk = list(itertools.islice(it, batch_rows))
             if not chunk:
                 return
+            _count_chunk("fused_chunks")
             try:
                 with np.errstate(divide="raise", invalid="raise",
                                  over="ignore", under="ignore"):
@@ -572,6 +719,7 @@ def make_fused(ingest, stages, emit, row_chain, batch_rows):
             except DeviceBackendError:
                 raise
             except Exception:
+                _count_chunk("fused_row_fallback_chunks")
                 with spans.span("flint.fused"):
                     out = list(row_chain(iter(chunk)))
             yield from out

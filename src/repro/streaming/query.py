@@ -45,6 +45,7 @@ See docs/streaming.md for the protocol write-up.
 
 from __future__ import annotations
 
+import decimal
 import operator
 import time
 
@@ -54,7 +55,8 @@ from repro.core.queues import watermark_message, watermark_ts
 from repro.core.scheduler import STREAM_PREFIX
 from repro.sql import plan as P
 from repro.sql.dataframe import DataFrame, _named
-from repro.sql.expr import AggExpr, Alias, Col, col, count_, max_
+from repro.sql.expr import (AggExpr, Alias, Col, col, count_, decimal_scale,
+                            div_half_up, max_, unscaled)
 from repro.sql.optimizer import PARTIAL_COMBINE_FACTOR, _row_width
 from repro.streaming.sources import ride_faults
 from repro.streaming.windows import WindowSpec, WindowState
@@ -85,11 +87,11 @@ class _ProtoRdd:
         self.nparts = 1
 
 
-def _decompose(named_aggs):
-    """Split user aggregates into algebraic slots that merge across
-    batches: returns (slot AggExprs, per-slot combiners, finalize fn).
-    ``collect_list`` is holistic — it cannot merge as a fixed-width slot
-    and is rejected for streams."""
+def _decompose(named_aggs, schema):
+    """Split user aggregates over ``schema`` into algebraic slots that
+    merge across batches: returns (slot AggExprs, per-slot combiners,
+    finalize fn). ``collect_list`` is holistic — it cannot merge as a
+    fixed-width slot and is rejected for streams."""
     slots, merges, layout = [], [], []
     for name, a in named_aggs:
         off = len(slots)
@@ -100,21 +102,30 @@ def _decompose(named_aggs):
             slots.append(AggExpr("sum", a.child, name=f"__s{off}"))
             slots.append(AggExpr("count", None, name=f"__s{off + 1}"))
             merges += [_SLOT_MERGE["sum"], _SLOT_MERGE["count"]]
-            layout.append(("avg", off))
+            layout.append((_merged_avg(a, schema), off))
         else:  # sum/count/min/max merge with their own combiner
             slots.append(AggExpr(a.op, a.child, name=f"__s{off}"))
             merges.append(_SLOT_MERGE[a.op])
-            layout.append(("id", off))
+            layout.append((None, off))
 
     def finalize(vals):
-        out = []
-        for kind, off in layout:
-            if kind == "avg":
-                out.append(vals[off] / vals[off + 1])
-            else:
-                out.append(vals[off])
-        return out
+        return [vals[off] if avg is None else avg(vals[off], vals[off + 1])
+                for avg, off in layout]
     return slots, merges, finalize
+
+
+def _merged_avg(a: AggExpr, schema):
+    """(merged sum, count) -> avg as a batch query answers it: a float,
+    or for a decimal, whose merged sum arrives as a ``decimal.Decimal``,
+    the quotient rounded half up at the avg's scale (s + 4)."""
+    scale = decimal_scale(a.dtype(schema))
+    if scale is None:
+        return operator.truediv
+
+    def avg(total, count):
+        q = div_half_up(unscaled(total, scale), count)
+        return decimal.Decimal(f"{q}E-{scale}")
+    return avg
 
 
 class StreamFrame:
@@ -214,7 +225,7 @@ class WindowedGrouped:
                 raise TypeError(f"agg() takes aggregate expressions, "
                                 f"got {a!r}")
             named.append((a.name, a))
-        slots, merges, finalize = _decompose(named)
+        slots, merges, finalize = _decompose(named, self.ws.proto.schema())
         spec = self.ws.spec
         ts = spec.ts_col
         batch_aggs = ([a.alias(a.name) for a in slots]

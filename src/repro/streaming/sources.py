@@ -26,7 +26,7 @@ import random
 import time
 
 from repro.core.retry import TransientServiceError
-from repro.sql.expr import CASTS, Schema
+from repro.sql.expr import Schema, scan_cast
 
 
 def ride_faults(fn, *args):
@@ -105,7 +105,7 @@ class S3PrefixTailer:
         self.store = store
         self.prefix = prefix
         self.schema = schema if isinstance(schema, Schema) else Schema(schema)
-        self._casts = [CASTS[t] for _, t in self.schema]
+        self._casts = [scan_cast(t) for _, t in self.schema]
         self._sealed = False
 
     def seal(self) -> None:

@@ -605,8 +605,8 @@ def test_dataframe_take_and_misc_guards():
     assert df.take(3) == [(0, 0), (1, 1), (2, 2)]
     with pytest.raises(ValueError, match="n >= 0"):
         df.limit(-1)
-    with pytest.raises(ValueError, match="at least one key"):
-        df.groupBy()
+    # groupBy() with no keys is a global aggregate: one row
+    assert df.groupBy().agg(count_().alias("n")).collect() == [(10,)]
     with pytest.raises(ValueError, match="at least one key"):
         df.orderBy()
     with pytest.raises(ValueError, match="at least one aggregate"):

@@ -84,28 +84,46 @@ def _bigint_fold(vals, ids, n):
     return acc
 
 
+def _stacked(cols, ids, groups):
+    """K columns as one grouped sum: column j's rows in buckets
+    j * groups + id."""
+    k = len(cols)
+    return (np.concatenate(cols), np.concatenate(
+        [np.asarray(ids) + j * groups for j in range(k)]), k * groups)
+
+
 @pytest.mark.parametrize("case,path", [
-    ("small", "kernel_calls"),      # sum(|v|) < 2**24: the one-hot kernel
+    ("small", "kernel_calls"),      # sum(|v|) < 2**24: one limb
     ("near_2_24", "kernel_calls"),  # one partial climbs to 2**24 - 1
-    ("big", "x64_sums"),            # up to 2**62: the x64 segment sum
+    ("limbs", "kernel_calls"),      # up to 2**40: 12-bit limbs
+    ("big", "x64_sums"),            # past the kernel's limb columns
     ("wide", "x64_sums"),           # more groups than the kernel takes
     ("over", "device_fallbacks"),   # past 2**62: handed back to the host
     ("int64_min", "device_fallbacks"),
 ])
 def test_grouped_reduce_envelopes_are_exact(case, path):
     """Each envelope of grouped_reduce gives the bigint fold's int64 sums
-    (or None past 2**62), and counts the path it took."""
+    (or None past 2**62), and counts the path it took, its columns and
+    its limb columns."""
     rng = np.random.default_rng(7)
     n, groups = 3000, 16
     ids = rng.integers(0, groups, size=n)
+    cols, limbs = 1, 1
     if case == "small":
         vals = rng.integers(-50, 50, size=n)
     elif case == "near_2_24":
         ids = np.zeros(n, dtype=np.int64)
         vals = rng.integers(1, 2**12, size=n)
         vals[-1] += 2**24 - 1 - int(vals.sum())
-    elif case == "big":
+    elif case == "limbs":
         vals = rng.integers(-2**40, 2**40, size=n)
+        limbs = 8  # 3,000 rows take 12-bit limbs: 4 of each sign
+    elif case == "big":
+        cols = 5  # 5 x 8 limb columns are more than the kernel takes
+        vals, ids, groups = _stacked(
+            [rng.integers(-2**40, 2**40, size=n) for _ in range(cols)],
+            ids, groups)
+        limbs = cols
     elif case == "wide":
         groups = ops._MAX_KERNEL_GROUPS + 1
         ids = np.arange(n) % groups
@@ -115,16 +133,79 @@ def test_grouped_reduce_envelopes_are_exact(case, path):
     else:
         vals = np.array([-2**63] + [0] * (n - 1), dtype=np.int64)
     stats = {}
-    got = ops.grouped_reduce(vals, ids, groups, stats=stats)
+    got = ops.grouped_reduce(vals, ids, groups, columns=cols, stats=stats)
     if path == "device_fallbacks":
         assert stats == {path: 1}
         assert got is None
     else:
         # 3,000 rows pad to the next power of two
         assert stats == {path: 1, "device_rows": n,
-                         "device_padded_rows": 4096}
+                         "device_padded_rows": 4096,
+                         "device_sum_columns": cols,
+                         "device_limb_columns": limbs}
         assert got.dtype == np.int64
         assert got.tolist() == _bigint_fold(vals, ids, groups)
+
+
+#: TPC-H Q1's sums at full magnitude (unscaled decimals): quantity at
+#: scale 2, extended price, discounted price at scale 4, charge at scale
+#: 6 (to 1.1e11), discount
+Q1_COLUMNS = ((100, 5000), (90100, 10495000), (810900, 104950000),
+              (810900, 113346000000), (0, 10))
+
+
+@pytest.mark.parametrize("n", [1, 700, 3000, 8192])
+@pytest.mark.parametrize("signed", [False, True])
+def test_limb_sums_are_exact_at_q1_magnitudes(n, signed):
+    """All of a chunk's sums in one kernel program, limb by limb, equal
+    numpy's int64 sums: K columns at Q1's magnitudes, signed or not, any
+    row count (the padding rows add nothing)."""
+    rng = np.random.default_rng(n + signed)
+    groups = 4
+    ids = rng.integers(0, groups, size=n)
+    cols = []
+    for lo, hi in Q1_COLUMNS:
+        c = rng.integers(lo, hi + 1, size=n)
+        if signed:
+            c = c * rng.choice([-1, 1], size=n)
+        cols.append(c)
+    vals, fids, buckets = _stacked(cols, ids, groups)
+    stats = {}
+    got = ops.grouped_reduce(vals, fids, buckets, columns=len(cols),
+                             stats=stats)
+    want = np.zeros((len(cols), groups), dtype=np.int64)
+    for j, c in enumerate(cols):
+        np.add.at(want[j], ids, c)
+    assert got.tolist() == want.ravel().tolist()
+    assert stats["kernel_calls"] == 1 and "x64_sums" not in stats
+    assert stats["device_sum_columns"] == len(cols)
+    assert len(cols) <= stats["device_limb_columns"] <= ops._MAX_LIMBS
+
+
+def test_limb_width_keeps_each_limb_sum_below_2_24():
+    for n in (1, 2, 1000, 4096, 8192, 2**24 - 1):
+        w = ops.limb_width(n)
+        assert n * (2**w - 1) < 2**24 <= n * (2**(w + 1) - 1)
+    assert ops.limb_width(8192) == 11
+    assert ops.limb_width(2**24) == 0
+
+
+def test_q1_chunk_is_one_program_of_13_limb_columns():
+    """A full 8,192-row chunk of Q1's five distinct sums: quantity in 2
+    limbs, price and discounted price in 3, charge in 4, discount whole:
+    13 limb columns, one program."""
+    rng = np.random.default_rng(3)
+    n, groups = 8192, 4
+    ids = rng.integers(0, groups, size=n)
+    cols = [np.full(n, hi) for _, hi in Q1_COLUMNS]
+    vals, fids, buckets = _stacked(cols, ids, groups)
+    stats = {}
+    got = ops.grouped_reduce(vals, fids, buckets, columns=5, stats=stats)
+    assert stats["kernel_calls"] == 1
+    assert stats["device_limb_columns"] == 13
+    counts = np.bincount(ids, minlength=groups)
+    assert got.tolist() == [hi * int(c) for _, hi in Q1_COLUMNS
+                            for c in counts]
 
 
 def test_grouped_reduce_empty_chunk_launches_nothing():

@@ -25,7 +25,8 @@ def test_grouped_reduce_counts_rows_and_padded_rows(n, padded):
     stats = {}
     got = ops.grouped_reduce(vals, ids, 5, stats=stats)
     assert stats == {"kernel_calls": 1, "device_rows": n,
-                     "device_padded_rows": padded}
+                     "device_padded_rows": padded,
+                     "device_sum_columns": 1, "device_limb_columns": 1}
     assert got.tolist() == [int(vals[ids == g].sum()) for g in range(5)]
     ops.grouped_reduce(vals, ids, 5, stats=stats)
     assert stats["device_rows"] == 2 * n

@@ -8,6 +8,7 @@ late-data accounting, the per-window transport cost model, and the
 service integration (long-running admission + between-batch quota)."""
 
 import os
+from decimal import ROUND_HALF_UP, Context, Decimal
 
 import pytest
 from hypothesis import given, settings
@@ -270,6 +271,32 @@ def test_transform_ops_and_avg_match_batch():
                        pred=lambda ts, k, v: v >= 100)
     assert got == [(ws, we, k, 2 * t, n, 2 * t / n)
                    for ws, we, k, t, n in ref]
+    q.cleanup()
+    assert_no_leaks(ctx)
+
+
+def test_decimal_avg_matches_batch_rounding():
+    """A decimal avg merges as sum + count across batches and finishes
+    as the batch engine's: scale s + 4, rounded half up."""
+    ctx = FlintContext("flint", _cfg("sqs"))
+    src = EventGenerator(seed=3, total=200, rate=10, max_delay=2)
+    q = (read_stream(ctx, src)
+         .withColumn("m", col("val").cast("decimal(9,2)")
+                     * lit(Decimal("0.01")))
+         .window("ts", 20)
+         .groupBy("key")
+         .agg(sum_(col("m")).alias("t"), avg_(col("m")).alias("a"))
+         .start("dec", allowed_lateness=2, batch_size=70))
+    got = q.run()
+    exact = Context(prec=60)
+    ref = py_reference(src.read(0, 200), 20)
+    # m has scale 2 + 2, so its avg has scale 8
+    want = [(ws, we, k, Decimal(t) / 100,
+             exact.divide(Decimal(t) / 100, n).quantize(
+                 Decimal("1E-8"), rounding=ROUND_HALF_UP))
+            for ws, we, k, t, n in ref]
+    assert got == want
+    assert all(type(r[4]) is Decimal for r in got)
     q.cleanup()
     assert_no_leaks(ctx)
 

@@ -86,3 +86,31 @@ def test_x64_segment_sum_compiles(one_chip):
             _spec((8192,), jnp.int32, one_chip),
             n_buckets=8192).compile()
     assert compiled.memory_analysis().output_size_in_bytes == 8192 * 8
+
+
+@pytest.mark.parametrize("limbs,groups", [
+    (16, 128),    # TPC-H Q1's 13 limb columns over its 4 groups
+    (32, 8192),   # the most limb columns over the most groups
+])
+def test_limb_columns_compile(one_chip, limbs, groups):
+    """A full chunk of limb columns, every sum of a chunk side by side,
+    compiles into one Mosaic call and fits the chip's memory."""
+    compiled = ops._kernel_sums.lower(
+        _spec((8192, limbs), jnp.float32, one_chip),
+        _spec((8192,), jnp.int32, one_chip),
+        n_buckets=groups, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().output_size_in_bytes == (
+        groups * limbs * 4)
+
+
+def test_x64_columns_compile(one_chip):
+    """Columns past the limb budget take one x64 segment sum side by
+    side."""
+    with jax.enable_x64(True):
+        compiled = ops._x64_sums.lower(
+            _spec((8192, 5), jnp.int64, one_chip),
+            _spec((8192,), jnp.int32, one_chip),
+            n_buckets=8192).compile()
+    # the chip's layout pads 5 columns to 8
+    assert compiled.memory_analysis().output_size_in_bytes >= 8192 * 5 * 8
