@@ -31,8 +31,10 @@ from __future__ import annotations
 
 import contextvars
 import dataclasses
+import datetime
 import hashlib
 import itertools
+import operator
 import os
 import pickle
 import threading
@@ -50,9 +52,9 @@ from repro.core.queues import ObjectStoreSim, SQSSim
 from repro.core.retry import (RetryBudget, RetryBudgetExhausted,
                               RetryExhausted, RetryingStore, RetryPolicy,
                               TransientServiceError)
-from repro.core.shuffle import (KVBatch, TransportSet, iter_records,
-                                pack_batch, pack_batch_columns, queue_name,
-                                unpack_batch)
+from repro.core.shuffle import (SLOT_MERGE, KVBatch, TransportSet,
+                                iter_records, pack_batch, pack_batch_columns,
+                                queue_name, unpack_batch)
 from repro.core.shuffle.base import AbortedError  # noqa: F401 (re-export:
 #                       pre-subsystem callers import it from here)
 from repro.core.shuffle.base import LostShuffleInput
@@ -790,6 +792,21 @@ def _canonical_key(key):
     return key
 
 
+#: key field types whose equal values always route alike (``1``, ``1.0``
+#: and ``True`` all canonicalize to ``1``): only keys made of these may
+#: share the column-wise combine's route memo and fold by key equality.
+#: Decimal is not one: ``Decimal("1.0") == Decimal("1.00")`` pickles apart
+_ROUTE_EXACT = frozenset({int, float, bool, str, bytes, type(None),
+                          datetime.date})
+
+
+class _SlotAcc(list):
+    """The running partials of one key under the column-wise map-side
+    combine, folded in place; the value tuple is built once, at flush."""
+
+    __slots__ = ()
+
+
 class _ColumnBuffer:
     """Per-partition column-major output buffer: rows routed here from
     KVBatch carriers never transpose back to tuples — flush packs wire
@@ -858,6 +875,13 @@ class _ShuffleWriter:
                              else write.partition_fn)
         self.buffers: dict[int, Any] = {}
         self.buffered = 0
+        # the column-wise map-side combine (_fold_batch): key -> partition
+        # for the task, and key -> its _SlotAcc in ``buffers`` until the
+        # next flush; records it folded, and records ``add`` combined
+        self._routes: dict = {}
+        self._accs: dict = {}
+        self.combine_batch_records = 0
+        self.combine_row_records = 0
         self.seq = {int(k): v for k, v in (seq_start or {}).items()}
         # per-output-partition [wire bytes, records] — reported back to
         # the scheduler as stats["shuffle_out"], the measured volume the
@@ -891,10 +915,19 @@ class _ShuffleWriter:
             k, v = record
             p = self._partition_of(k)
             if w.mode == "agg" and self.combine is not None:
+                self.combine_row_records += 1
                 buf = self.buffers.setdefault(p, {})
-                before = len(buf)
-                buf[k] = self.combine(buf[k], v) if k in buf else v
-                self.buffered += len(buf) - before
+                if k in buf:
+                    acc = buf[k]
+                    if type(acc) is _SlotAcc:
+                        # the column-wise fold holds this key: hand its
+                        # partials back to the record path as a tuple
+                        del self._accs[k]
+                        acc = tuple(acc)
+                    buf[k] = self.combine(acc, v)
+                    return
+                buf[k] = v
+                self.buffered += 1
                 if self.buffered >= self.env.cfg.flush_records:
                     self._flush()
                 return
@@ -915,13 +948,21 @@ class _ShuffleWriter:
         buf.append(record)
 
     def add_batch(self, batch: KVBatch):
-        """Column-major fast path for fused vectorized operators. Map-side
-        combine still folds record-at-a-time (the combine dict's insertion
-        order and flush boundaries must not depend on how the stream was
-        batched); group/join/plain shuffles keep the columns intact per
+        """Column-major fast path for fused vectorized operators. Under a
+        map-side combine, grouped partials (a batch that states its slot
+        ops, keys of ``_ROUTE_EXACT`` types) fold column-wise
+        (``_fold_batch``); any other batch folds record-at-a-time through
+        ``add``. Either way the combine dicts' insertion order, flush
+        boundaries and wire bodies are those of adding the batch's records
+        one by one. Group/join/plain shuffles keep the columns intact per
         output partition so flush() packs without transposing."""
         w = self.write
-        if w.mode == "repart" or (w.mode == "agg" and self.combine is not None):
+        combine = w.mode == "agg" and self.combine is not None
+        if combine and batch.ops is not None and all(
+                _ROUTE_EXACT.issuperset(map(type, c)) for c in batch.kcols):
+            self._fold_batch(batch)
+            return
+        if w.mode == "repart" or combine:
             for rec in batch.iter_rows():
                 self.add(rec)
             return
@@ -943,6 +984,37 @@ class _ShuffleWriter:
         self.buffered += batch.n
         if self.buffered >= self.env.cfg.flush_records:
             self._flush()
+
+    def _fold_batch(self, batch: KVBatch):
+        """Fold grouped partials into the combine dicts as ``add`` would
+        fold their records, with the batch's slot ops in place of the
+        opaque combine: a key already folded since the last flush costs
+        one lookup and one ``SLOT_MERGE`` call a slot; a new one its route
+        (memoized for the task: equal keys of ``_ROUTE_EXACT`` types route
+        alike) and its place in its partition's dict. A key the record
+        path combined is taken over as it stands."""
+        merges = [SLOT_MERGE[op] for op in batch.ops]
+        call = operator.call
+        accs = self._accs
+        self.combine_batch_records += batch.n
+        for k, v in zip(batch.key_tuples(), zip(*batch.vcols)):
+            acc = accs.get(k)
+            if acc is not None:
+                acc[:] = map(call, merges, acc, v)
+                continue
+            p = self._routes.get(k)
+            if p is None:
+                p = self._routes[k] = self._partition_of(k)
+            buf = self.buffers.get(p)
+            if buf is None:
+                buf = self.buffers[p] = {}
+            if k in buf:
+                buf[k] = accs[k] = _SlotAcc(map(call, merges, buf[k], v))
+                continue
+            buf[k] = accs[k] = _SlotAcc(v)
+            self.buffered += 1
+            if self.buffered >= self.env.cfg.flush_records:
+                self._flush()
 
     def add_all(self, records):
         """Route every record (or KVBatch carrier) of ``records`` under
@@ -978,7 +1050,12 @@ class _ShuffleWriter:
                     cb, limit=transport.batch_limit, spill=transport.spill,
                     columnar=self.env.cfg.columnar_batches)
             else:
-                records = list(buf.items()) if isinstance(buf, dict) else buf
+                records = buf
+                if isinstance(buf, dict):
+                    # partials the column-wise fold holds become tuples
+                    records = ([(k, tuple(v) if type(v) is _SlotAcc else v)
+                                for k, v in buf.items()] if self._accs
+                               else list(buf.items()))
                 if not records:
                     continue
                 nrecs = len(records)
@@ -996,6 +1073,7 @@ class _ShuffleWriter:
             st[1] += nrecs
         self.buffers = {}
         self.buffered = 0
+        self._accs.clear()
 
     def finalize(self):
         """Emit EOS on every output partition — INCLUDING partitions this
@@ -1110,6 +1188,8 @@ def _run_task(payload: dict, env: LambdaSim, stats: dict) -> dict:
                     f"> cap {env.cfg.agg_memory_records}")
         writer.add_all(out_iter)
         writer.flush()
+        stats["combine_batch_records"] = writer.combine_batch_records
+        stats["combine_row_records"] = writer.combine_row_records
         # per-link deltas: the scheduler sums links/attempts per shuffle
         stats["shuffle_out"] = {p: list(v)
                                 for p, v in writer.out_stats.items()}

@@ -225,8 +225,10 @@ class FlintScheduler:
         # sums, x64 segment sums, and sums past 2**62 handed back to the
         # host's exact path, with the value and limb columns and the rows
         # they summed; the chunks the fused operator ran, and re-ran on
-        # the row path; and the scan chunks it parsed column-wise or line
-        # by line — summed over successful task responses
+        # the row path; the scan chunks it parsed column-wise or line by
+        # line; and the records the shuffle writers' map-side combine
+        # folded column-wise from grouped partials, or one by one —
+        # summed over successful task responses
         self.device_stats = {"kernel_calls": 0, "x64_sums": 0,
                              "device_fallbacks": 0, "device_rows": 0,
                              "device_padded_rows": 0,
@@ -235,7 +237,9 @@ class FlintScheduler:
                              "fused_chunks": 0,
                              "fused_row_fallback_chunks": 0,
                              "ingest_columnar_chunks": 0,
-                             "ingest_line_chunks": 0}
+                             "ingest_line_chunks": 0,
+                             "combine_batch_records": 0,
+                             "combine_row_records": 0}
         self._dispatch_sleep = 0.0  # decorrelated-jitter state, 0 = idle
         self._backoff_rng = random.Random(plan.seed ^ 0x5DEECE66D)
         self._stage_retries: dict[int, int] = {}  # stage idx -> resubmits

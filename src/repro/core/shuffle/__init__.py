@@ -15,9 +15,9 @@ import threading
 from repro.core.retry import RetryPolicy
 from repro.core.shuffle.base import (AbortedError, DrainHandle, DrainState,
                                      LostShuffleInput, ShuffleTransport)
-from repro.core.shuffle.batch import (KVBatch, is_columnar, iter_records,
-                                      pack_batch, pack_batch_columns,
-                                      unpack_batch)
+from repro.core.shuffle.batch import (SLOT_MERGE, KVBatch, is_columnar,
+                                      iter_records, pack_batch,
+                                      pack_batch_columns, unpack_batch)
 from repro.core.shuffle.s3 import S3ExchangeTransport
 from repro.core.shuffle.sqs import SQSTransport, queue_name
 
@@ -77,6 +77,6 @@ class TransportSet:
 __all__ = ["AbortedError", "DrainHandle", "DrainState", "LostShuffleInput",
            "ShuffleTransport",
            "SQSTransport", "S3ExchangeTransport", "TransportSet",
-           "KVBatch", "is_columnar", "iter_records", "pack_batch",
-           "pack_batch_columns", "unpack_batch", "queue_name",
+           "KVBatch", "SLOT_MERGE", "is_columnar", "iter_records",
+           "pack_batch", "pack_batch_columns", "unpack_batch", "queue_name",
            "register_transport", "transport_names"]

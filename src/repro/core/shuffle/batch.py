@@ -32,6 +32,7 @@ same record sequence (asserted in tests/test_columnar_batches.py).
 
 from __future__ import annotations
 
+import operator
 import struct
 from typing import Any, Callable, Iterable
 
@@ -47,6 +48,11 @@ _SLEN = struct.Struct("<H")
 # nested tuple sub-column prefixes; schemas are tens of bytes, the caps are
 # hundreds of KiB, so a flat reserve beats exact bookkeeping
 _BODY_RESERVE = 512
+
+#: the fold of each slot op of grouped partials, argument order
+#: (accumulated, new): the SQL map-side combine's ``merge`` and the shuffle
+#: writer's column-wise fold apply these same functions
+SLOT_MERGE = {"sum": operator.add, "min": min, "max": max}
 
 
 def pack_batch(records: Iterable[Any], limit: int = SQS_MESSAGE_LIMIT,
@@ -104,31 +110,35 @@ class KVBatch:
     tuple field, all the same length ``n`` — so core never needs numpy.
     Keys and values are always tuples on the wire for SQL shuffles, hence
     the per-field layout; ``kschema``/``vschema`` are the matching
-    ``t(...)`` serde schemas (or None when the plan declared none)."""
+    ``t(...)`` serde schemas (or None when the plan declared none).
 
-    __slots__ = ("kcols", "vcols", "kschema", "vschema", "n")
+    ``ops``, when set, names the slot op of each value column (a key of
+    ``SLOT_MERGE``): the batch then holds grouped partials, each key once,
+    and a map-side combine may fold it column-wise with those ops instead
+    of its opaque combine function. A global aggregate's partials have no
+    key columns: every key is ``()``."""
 
-    def __init__(self, kcols, vcols, kschema=None, vschema=None):
-        if not kcols or not vcols:
-            raise ValueError("KVBatch needs at least one key and value col")
+    __slots__ = ("kcols", "vcols", "kschema", "vschema", "ops", "n")
+
+    def __init__(self, kcols, vcols, kschema=None, vschema=None, ops=None):
+        if not vcols or not (kcols or ops):
+            raise ValueError("KVBatch needs a value col, and a key col "
+                             "unless it holds partials")
         self.kcols = kcols
         self.vcols = vcols
         self.kschema = kschema
         self.vschema = vschema
-        self.n = len(kcols[0])
+        self.ops = ops
+        self.n = len(vcols[0])
 
     def key_tuples(self) -> list:
+        if not self.kcols:
+            return [()] * self.n
         return list(zip(*self.kcols))
 
     def iter_rows(self):
         """Expand back to the row representation: (key_tuple, val_tuple)."""
-        return zip(zip(*self.kcols), zip(*self.vcols))
-
-    def select(self, idxs) -> "KVBatch":
-        """A new batch holding the rows at ``idxs`` (in that order)."""
-        return KVBatch([[c[i] for i in idxs] for c in self.kcols],
-                       [[c[i] for i in idxs] for c in self.vcols],
-                       self.kschema, self.vschema)
+        return zip(self.key_tuples(), zip(*self.vcols))
 
 
 def iter_records(it: Iterable[Any]):

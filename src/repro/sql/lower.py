@@ -45,15 +45,13 @@ steps lower as row operators exactly as above.
 from __future__ import annotations
 
 import bisect
-import operator
 
 from repro.core import rdd as R
+from repro.core.shuffle import SLOT_MERGE as _SLOT_MERGE
 from repro.sql import plan as P
 from repro.sql import vectorized as V
 from repro.sql.expr import (Schema, decimal_scale, dtype_serde_char,
                              internal_value, scan_cast)
-
-_SLOT_MERGE = {"sum": operator.add, "min": min, "max": max}
 
 
 def _one(row):

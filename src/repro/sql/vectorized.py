@@ -547,9 +547,11 @@ def make_kv_plain_emit(key_fns, rest_idx, kschema, vschema):
 
 def make_kv_agg_emit(key_fns, slot_fns, slot_ops, backend):
     """Partial aggregation: group the batch by key and fold each slot
-    column, emitting one (key, partials) record per distinct key in
-    FIRST-OCCURRENCE order — the same order the row path's combine dict
-    discovers keys, so writer flush boundaries and wire bodies match."""
+    column, emitting the chunk's grouped partials as one ``KVBatch`` that
+    states its slot ops: a row per distinct key in FIRST-OCCURRENCE order
+    (the order the row path's combine dict discovers keys), which the
+    shuffle writer's map-side combine folds column-wise, with flush
+    boundaries and wire bodies those of the same records one by one."""
     def emit(cols, n):
         key_cols = [f(cols, n) for f in key_fns]
         slot_cols = [f(cols, n) for f in slot_fns]
@@ -558,10 +560,14 @@ def make_kv_agg_emit(key_fns, slot_fns, slot_ops, backend):
 
 
 def grouped_records(key_cols, slot_cols, slot_ops, n, backend="numpy"):
+    """The chunk's partials: ``[KVBatch]`` of its distinct keys and their
+    folded slot columns, stating ``slot_ops`` (``[]`` for an empty
+    chunk)."""
     if n == 0:
         return []
     if key_cols:
-        keys = list(zip(*[to_list(c, n) for c in key_cols]))
+        klists = [to_list(c, n) for c in key_cols]
+        keys = list(zip(*klists))
         index: dict = {}
         gids = np.empty(n, dtype=np.int64)
         first = []
@@ -572,11 +578,12 @@ def grouped_records(key_cols, slot_cols, slot_ops, n, backend="numpy"):
                 index[k] = g
                 first.append(i)
             gids[i] = g
-        uniq = list(index)  # insertion order == first occurrence
-    else:  # a global aggregate: one group
+        # key columns of the distinct keys, in first-occurrence order
+        kcols = [list(map(kl.__getitem__, first)) for kl in klists]
+    else:  # a global aggregate: one group, key ()
         gids = np.zeros(n, dtype=np.int64)
-        first, uniq = [0], [()]
-    ng = len(uniq)
+        first, kcols = [0], []
+    ng = len(first)
     first_arr = np.array(first, dtype=np.int64)
     # a column that several slots fold alike (sum(x) beside avg(x)) is
     # folded once
@@ -597,8 +604,7 @@ def grouped_records(key_cols, slot_cols, slot_ops, n, backend="numpy"):
             folded[key] = to_list(
                 _fold_slot(op, c, gids, ng, first_arr, n), ng)
         out_slots.append(folded[key])
-    return [(k, tuple(s[g] for s in out_slots))
-            for g, k in enumerate(uniq)]
+    return [KVBatch(kcols, out_slots, ops=list(slot_ops))]
 
 
 def _fold_slot(op, col, gids, ng, first, n):
@@ -632,9 +638,11 @@ def _fold_slot(op, col, gids, ng, first, n):
         np.add.at(acc, gids[rest], col[rest])
         return acc
     if op in ("min", "max"):
-        if col.dtype == np.float64 and np.isnan(col).any():
-            # Python's min/max keep the FIRST operand on NaN; numpy
-            # either propagates (minimum) or ignores (fmin) it
+        if col.dtype == np.float64 and (np.isnan(col).any()
+                                        or np.signbit(col[col == 0]).any()):
+            # Python's min/max keep the FIRST operand on NaN and on
+            # -0.0 == 0.0; numpy either propagates (minimum) or ignores
+            # (fmin) NaN, and may return either zero
             return _py_fold(op, col.tolist(), gids, ng)
         acc = col[first].copy()
         rest = np.ones(n, dtype=np.bool_)

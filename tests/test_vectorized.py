@@ -22,6 +22,7 @@ from hypothesis import strategies as st
 
 from repro.core import FaultPlan, FlintConfig, FlintContext
 from repro.core import rdd as R
+from repro.core.shuffle import iter_records
 from repro.sql import (Schema, avg_, col, collect_list, count_, lit, max_,
                        min_, sum_, udf)
 from repro.sql import expr as E
@@ -252,7 +253,8 @@ def test_grouped_fold_matches_row_fold(seed):
     try:
         with np.errstate(divide="raise", invalid="raise",
                          over="ignore", under="ignore"):
-            got = V.grouped_records(kcols, slot_cols, slot_ops, n, backend)
+            got = list(iter_records(
+                V.grouped_records(kcols, slot_cols, slot_ops, n, backend)))
     except FloatingPointError:
         return  # inf/-inf collisions etc.: the fused op re-runs row-wise
     refs = [_ref_fold(op, key_vals, vals)
@@ -262,6 +264,20 @@ def test_grouped_fold_matches_row_fold(seed):
     for k, partials in got:
         for slot, ref in zip(partials, refs):
             assert _same(slot, ref[k]), (slot_ops, k, slot, ref[k])
+
+
+@pytest.mark.parametrize("zeros", [(0.0, -0.0), (-0.0, 0.0)],
+                         ids=["plus-first", "minus-first"])
+@pytest.mark.parametrize("op", ["min", "max"])
+def test_grouped_min_max_keep_the_first_of_equal_zeros(op, zeros):
+    """-0.0 == 0.0: Python's min/max keep the first operand, as the row
+    path's fold does; numpy's minimum/maximum may return either."""
+    vals = [2.5, zeros[0], zeros[1], 2.5] if op == "min" else \
+        [-2.5, zeros[0], zeros[1], -2.5]
+    got = list(iter_records(V.grouped_records(
+        [np.zeros(4, dtype=np.int64)], [np.array(vals)], [op], 4)))
+    want = _ref_fold(op, [0] * 4, vals)[0]
+    assert [k for k, _ in got] == [(0,)] and _same(got[0][1][0], want)
 
 
 # ------------------------------------------------------------- engine A/B
